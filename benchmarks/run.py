@@ -1453,6 +1453,8 @@ def main() -> None:
     args = ap.parse_args()
     trials = 5 if args.quick else args.trials
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     os.makedirs(RESULTS, exist_ok=True)
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     results = {}

@@ -53,9 +53,9 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from ..core import (BuildReport, Instruction, LayerStore, PassiveRegistry,
-                    RelayNode, diff_image, fingerprint_tree,
-                    fingerprint_tree_packed, inject_image_multi, push_delta,
-                    replicate_fanout)
+                    RelayNode, StructureChangeError, diff_image,
+                    fingerprint_tree, fingerprint_tree_packed,
+                    inject_image_multi, push_delta, replicate_fanout)
 from ..ft.faults import CrashInjected
 
 
@@ -360,11 +360,9 @@ class CheckpointManager:
                 self.tag_of(step), diffs,
                 providers={k: (lambda p=v: p) for k, v in payloads.items()},
                 durability=self.policy.durability)
-        except CrashInjected:
-            raise           # simulated SIGKILL: the process is gone, it
-            # cannot fall back to a full rebuild "after" dying
-        except Exception:  # noqa: BLE001
-            # structure changed ("compiled" case) -> rebuild fall-back
+        except StructureChangeError:
+            # structure changed ("compiled" case) -> rebuild fall-back;
+            # any other failure of the injection path is a failed save
             report = self._save_full(step, payloads,
                                      fps=new_fps if new_fps else None)
         report.bytes_d2h += stats.get("bytes_d2h", 0)

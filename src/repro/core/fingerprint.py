@@ -168,11 +168,14 @@ def tree_pack_index(tree, chunk_bytes: int
 
 def _pack_rows(leaves: Tuple[jax.Array, ...],
                geom: Tuple[Tuple[int, int], ...],
-               lanes: int) -> Tuple[jax.Array, jax.Array]:
+               lanes: int, row_multiple: int = 1
+               ) -> Tuple[jax.Array, jax.Array]:
     """Trace-time packing: (total_chunks, lanes) uint32 buffer + per-row
     width vector. Rows keep each leaf's OWN zero padding inside its width
     (bit-identical to the per-leaf path); columns past the width are
-    masked out by the consumer."""
+    masked out by the consumer. Zero-width rows pad the row count up to
+    ``row_multiple`` inside the same concatenation, so a consumer that
+    blocks rows (the Pallas kernel) needs no second padded copy."""
     rows = []
     for arr, (n_chunks, w) in zip(leaves, geom):
         u = _to_u32_lanes(arr)
@@ -180,10 +183,13 @@ def _pack_rows(leaves: Tuple[jax.Array, ...],
         if w < lanes:
             u = jnp.pad(u, ((0, 0), (0, lanes - w)))
         rows.append(u)
+    widths = [np.full(g[0], g[1], np.int32) for g in geom]
+    pad = -sum(g[0] for g in geom) % row_multiple
+    if pad:
+        rows.append(jnp.zeros((pad, lanes), jnp.uint32))
+        widths.append(np.zeros(pad, np.int32))
     u_all = rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
-    widths = np.concatenate(
-        [np.full(g[0], g[1], np.int32) for g in geom]) if geom else \
-        np.zeros((0,), np.int32)
+    widths = np.concatenate(widths) if widths else np.zeros((0,), np.int32)
     return u_all, jnp.asarray(widths)
 
 
@@ -193,10 +199,12 @@ def _fingerprint_packed(leaves: Tuple[jax.Array, ...],
                         geom: Tuple[Tuple[int, int], ...],
                         lanes: int, backend: str, interpret: bool
                         ) -> jax.Array:
-    u_all, widths = _pack_rows(leaves, geom, lanes)
     if backend == "pallas":
-        from ..kernels.fingerprint.kernel import fingerprint_lanes
-        return fingerprint_lanes(u_all, widths=widths, interpret=interpret)
+        from ..kernels.fingerprint.kernel import ROWS, fingerprint_lanes
+        u_all, widths = _pack_rows(leaves, geom, lanes, row_multiple=ROWS)
+        fp = fingerprint_lanes(u_all, widths=widths, interpret=interpret)
+        return fp[:sum(g[0] for g in geom)]
+    u_all, widths = _pack_rows(leaves, geom, lanes)
     pos = jnp.arange(lanes, dtype=jnp.uint32)[None, :]
     mixed = _mix(u_all, pos)
     mixed = jnp.where(pos < widths.astype(jnp.uint32)[:, None],

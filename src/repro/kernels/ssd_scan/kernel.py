@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import compiler_params
-
 
 def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref,
                 y_ref, hout_ref, h_sc, *, chunk: int, n_chunks: int):
@@ -106,7 +104,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bc: jax.Array,
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt.astype(jnp.float32), Bc, Cc, A2, D2)

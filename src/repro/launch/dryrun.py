@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -14,8 +15,12 @@ Usage:
     python -m repro.launch.dryrun --all [--mesh pod|multipod|both]
     python -m repro.launch.dryrun --all --jobs 4      # subprocess per cell
 
-The XLA_FLAGS line above MUST stay the first statement — jax locks the
-device count at first init. Smoke tests / benches never import this module.
+The XLA_FLAGS and JAX_PLATFORMS lines above MUST stay the first
+statements — jax locks the platform and the device count at first init.
+The CPU pin keeps this tool (and every --all child, which inherits the
+environment) off an attached accelerator, whose few devices could not
+hold the production mesh anyway. Smoke tests / benches never import this
+module.
 """
 import argparse
 import json
@@ -50,7 +55,7 @@ def run_cell(arch: str, shape: str, mesh_name: str,
     from ..roofline import analyze_compiled
     from ..train import TrainConfig, make_decode_step, make_prefill_step, \
         make_train_step
-    from .mesh import make_production_mesh, mesh_context
+    from .mesh import make_production_mesh
 
     cfg = get_config(arch)
     if extra:
@@ -61,7 +66,7 @@ def run_cell(arch: str, shape: str, mesh_name: str,
     specs = input_specs(cfg, shape)
     t0 = time.perf_counter()
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if sp.kind == "train":
             tcfg = TrainConfig(recipe=recipe_override,
                                grad_reduce_dtype=grad_reduce_dtype,
@@ -150,6 +155,7 @@ def main() -> int:
     if not args.all:
         assert args.arch and args.shape
         meshes = ["pod", "multipod"] if args.mesh == "both" else [args.mesh]
+        ok = True
         for m in meshes:
             path = result_path(args.arch, args.shape, m, args.tag)
             try:
@@ -164,7 +170,8 @@ def main() -> int:
                 json.dump(d, f, indent=1, default=str)
             status = "OK" if d.get("ok") else f"FAIL ({d.get('error')})"
             print(f"[dryrun] {args.arch} x {args.shape} x {m}: {status}")
-        return 0
+            ok = ok and bool(d.get("ok"))
+        return 0 if ok else 1
 
     # --all: one subprocess per cell (isolation + bounded memory)
     todo = cells(args.mesh)

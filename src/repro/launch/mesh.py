@@ -7,43 +7,23 @@ axis carries only data parallelism + the inter-pod gradient all-reduce
 
 A FUNCTION, not a module constant: importing this module must never touch
 jax device state (the dry-run forces 512 host devices *before* first init).
-
-Compat: ``jax.sharding.AxisType`` / ``jax.set_mesh`` only exist on newer
-jax. On older releases (e.g. 0.4.x, the oldest CI cell) ``make_mesh`` drops
-the axis_types kwarg (Auto is the implicit behavior there) and
-``mesh_context`` falls back to the Mesh object itself, which is a context
-manager with the equivalent scoping semantics for everything this repo does
-(shard_map / with_sharding_constraint / NamedSharding-jit).
+Every axis is ``AxisType.Auto``: shardings come from the step's
+in/out_shardings and the activation rules, not from explicit-axis typing.
 """
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def mesh_context(mesh):
-    """``with mesh_context(mesh):`` — jax.set_mesh where available, the
-    Mesh-as-context-manager fallback otherwise."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Elastic-scaling entry: any (data, model) factorization of the
     currently-alive device set (see ft/elastic.py)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))

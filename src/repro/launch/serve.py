@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -16,10 +17,14 @@ import numpy as np
 from ..ckpt import CheckpointManager, CheckpointPolicy
 from ..configs import get_config, get_smoke_config
 from ..models import init_params
-from ..serve import Engine
+from ..serve import Engine, GenerationResult
+from .compile_cache import enable_compile_cache
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> GenerationResult:
+    """Serve one batch of synthetic requests; returns the generation (so
+    an in-process caller can check the tokens)."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -29,7 +34,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--steps", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.store:
@@ -55,6 +60,7 @@ def main() -> None:
     print(f"[serve] generated {toks} tokens in {dt:.2f}s "
           f"({toks / dt:.1f} tok/s)")
     print("[serve] first sequences:", res.tokens[:2, :8].tolist())
+    return res
 
 
 if __name__ == "__main__":

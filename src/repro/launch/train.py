@@ -2,9 +2,10 @@
 
 Wires together the full production stack: mesh, sharded train step,
 deterministic data pipeline, incremental (code-injection) checkpointing,
-watchdog + restart-resume. On this CPU container it is exercised with
-reduced configs (examples/quickstart.py); on a real slice the same file
-runs the full configs — nothing here is CPU-specific.
+watchdog + restart-resume. ``--smoke`` runs a reduced config
+(examples/quickstart.py on the CPU); ``chip_smoke.py`` at the repo root
+runs the published-width configs on a TPU — nothing here is CPU-specific.
+Called in-process, ``main(argv)`` returns a ``TrainRun``.
 
     PYTHONPATH=src python -m repro.launch.train --arch yi-6b --smoke \\
         --steps 50 --batch 8 --seq 64 --ckpt /tmp/ckpt
@@ -13,22 +14,41 @@ from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
+from typing import Any, Dict, Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from ..ckpt import CheckpointManager, CheckpointPolicy
+from ..ckpt import CheckpointManager, CheckpointPolicy, reshard_restore
 from ..configs import get_config, get_smoke_config
 from ..data import SyntheticTokens, make_global_batch
 from ..ft import Watchdog
 from ..models import init_params
 from ..optim import AdamWConfig, init_opt_state
 from ..train import TrainConfig, make_train_step
-from .mesh import make_mesh, mesh_context
+from .compile_cache import enable_compile_cache
+from .mesh import make_mesh
 
 
-def main() -> None:
+@dataclass
+class TrainRun:
+    """What ``main`` leaves behind for an in-process caller: the final
+    device-resident state, the last step's metrics (None when no step
+    ran) and the checkpoint manager, whose ``last_report`` describes the
+    last save."""
+    params: Any
+    opt_state: Any
+    metrics: Optional[Dict[str, Any]]
+    manager: Optional[CheckpointManager]
+
+
+def _specs(shardings):
+    return jax.tree.map(lambda s: s.spec, shardings)
+
+
+def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -44,7 +64,7 @@ def main() -> None:
     ap.add_argument("--full-ckpt", dest="incremental", action="store_false")
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--watchdog-s", type=float, default=0.0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     d, m = (int(x) for x in args.mesh.split("x"))
@@ -52,9 +72,6 @@ def main() -> None:
     tcfg = TrainConfig(adamw=AdamWConfig(peak_lr=args.lr,
                                          decay_steps=max(args.steps, 10)))
 
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    opt = init_opt_state(params)
-    start_step = 0
     mgr = None
     if args.ckpt:
         mgr = CheckpointManager(
@@ -62,18 +79,26 @@ def main() -> None:
             CheckpointPolicy(every_steps=args.ckpt_every,
                              incremental=args.incremental,
                              async_write=True))
-        restored = mgr.restore()
-        if restored is not None:
-            p_np, o_np, start_step = restored
-            params = jax.tree.map(jnp.asarray, p_np)
-            opt = jax.tree.map(jnp.asarray, o_np)
-            print(f"[train] resumed from step {start_step}")
 
     ds = SyntheticTokens(cfg.vocab, batch=args.batch, seq=args.seq)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         bundle = make_train_step(cfg, tcfg, mesh, args.batch, args.seq)
+        # state is created (or restored) straight into the step's
+        # shardings: nothing lands whole on one device first
+        p_sh, o_sh = bundle.in_shardings[:2]
+        restored = reshard_restore(mgr, mesh, _specs(p_sh), _specs(o_sh)) \
+            if mgr else None
+        if restored is not None:
+            params, opt, start_step = restored
+            print(f"[train] resumed from step {start_step}")
+        else:
+            params = jax.jit(lambda: init_params(cfg, jax.random.PRNGKey(0)),
+                             out_shardings=p_sh)()
+            opt = jax.jit(init_opt_state, out_shardings=o_sh)(params)
+            start_step = 0
         wd = Watchdog(args.watchdog_s, lambda: print("[watchdog] step hung")) \
             if args.watchdog_s > 0 else None
+        metrics = None
         t0 = time.perf_counter()
         for s in range(start_step, args.steps):
             host_batch = ds.batch_at(s)
@@ -102,6 +127,7 @@ def main() -> None:
         n_steps = args.steps - start_step
         print(f"[train] {n_steps} steps in {dt:.1f}s "
               f"({dt / max(n_steps, 1) * 1e3:.1f} ms/step)")
+    return TrainRun(params, opt, metrics, mgr)
 
 
 if __name__ == "__main__":

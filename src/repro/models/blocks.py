@@ -222,9 +222,7 @@ def _moe_local(cfg: ModelConfig, p: Dict, h: jax.Array, spec):
     psum pathologies of the SPMD-auto path disappear). Used when the
     rule table provides "moe_local" (small-expert archs under sp)."""
     from jax.sharding import PartitionSpec as P
-    from ..sharding.ctx import current_mesh, shard_map_fn
-    shard_map = shard_map_fn()
-    mesh = current_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     axes = tuple(a for e in tuple(spec) if e is not None
                  for a in (e if isinstance(e, tuple) else (e,)))
 
@@ -236,12 +234,8 @@ def _moe_local(cfg: ModelConfig, p: Dict, h: jax.Array, spec):
         aux = jax.lax.pmean(aux, axes)
         return y.reshape(B, S, d), aux
 
-    specs = dict(in_specs=(spec, P(), P(), P(), P()),
-                 out_specs=(spec, P()))
-    try:
-        fn = shard_map(body, mesh=mesh, check_rep=False, **specs)
-    except TypeError:     # newer jax renamed check_rep -> check_vma
-        fn = shard_map(body, mesh=mesh, check_vma=False, **specs)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, P(), P(), P(), P()),
+                       out_specs=(spec, P()), check_vma=False)
     return fn(h, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
 

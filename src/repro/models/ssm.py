@@ -69,7 +69,10 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bc: jax.Array,
         decay = Lq[:, :, None, :] - Lq[:, None, :, :]      # (B, i, j, H)
         ii = jnp.arange(Lq.shape[1])
         causal = (ii[:, None] >= ii[None, :])[None, :, :, None]
-        M = jnp.where(causal, jnp.exp(decay), 0.0) * \
+        # mask BEFORE exp: above the diagonal decay > 0 and exp overflows
+        # to inf once a chunk's decay sum passes ~88; where(mask, inf, 0)
+        # is fine forward but its gradient is 0 * inf = NaN
+        M = jnp.exp(jnp.where(causal, decay, -jnp.inf)) * \
             dtq[:, None, :, :]                             # (B, i, j, H)
         M = M.transpose(0, 3, 1, 2)                        # (B, H, i, j)
         cb_h = jnp.repeat(cb, rep, axis=1) if rep > 1 else cb  # (B,H,i,j)

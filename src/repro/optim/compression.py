@@ -23,15 +23,6 @@ import jax.numpy as jnp
 BLOCK = 2048
 
 
-def _axis_size(name):
-    """jax.lax.axis_size where it exists; psum(1) on older jax (0.4.x) —
-    the counting psum constant-folds at trace time inside shard_map."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(name)
-    return jax.lax.psum(1, name)
-
-
 def _blocks(x: jax.Array) -> jax.Array:
     flat = x.astype(jnp.float32).reshape(-1)
     pad = (-flat.size) % BLOCK
@@ -68,7 +59,7 @@ def compressed_psum(g: jax.Array, err: jax.Array, axis_names
         axis_names = (axis_names,)
     replicas = 1
     for a in axis_names:
-        replicas *= _axis_size(a)
+        replicas *= jax.lax.axis_size(a)
 
     target = _blocks(g) + _blocks(err)
     local_scale = jnp.max(jnp.abs(target), axis=1) / 127.0

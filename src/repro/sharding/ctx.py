@@ -18,27 +18,6 @@ _RULES: contextvars.ContextVar[Optional[Dict[str, PartitionSpec]]] = \
     contextvars.ContextVar("activation_rules", default=None)
 
 
-def shard_map_fn():
-    """``jax.shard_map`` (new home) falling back to
-    ``jax.experimental.shard_map.shard_map`` (0.4.x)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
-def current_mesh():
-    """The mesh in scope for shard_map: ``jax.sharding.get_abstract_mesh``
-    on newer jax; on older releases (0.4.x) the physical mesh entered via
-    the Mesh context manager (see launch.mesh.mesh_context)."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        return get_abstract()
-    from jax._src import mesh as mesh_lib
-    return mesh_lib.thread_resources.env.physical_mesh
-
-
 @contextlib.contextmanager
 def activation_ctx(rules: Dict[str, PartitionSpec]):
     token = _RULES.set(rules)

@@ -30,6 +30,19 @@ def test_chunked_matches_sequential(B, S, H, P, G, N, chunk):
     assert np.abs(np.asarray(h - h_ref)).max() < 2e-5
 
 
+def test_chunked_grad_finite_under_strong_decay():
+    """A chunk whose summed log-decay passes ~88 overflows exp above the
+    causal diagonal; the mask must keep that inf out of the gradient."""
+    B, S, H, P, G, N = 1, 64, 2, 4, 1, 8
+    x, dt, A, Bc, Cc, D = rand_inputs(jax.random.PRNGKey(3), B, S, H, P, G, N)
+    A = jnp.full((H,), -8.0)          # |sum dt*A| over 64 steps >> 88
+
+    def loss(dt):
+        return ssd_chunked(x, dt, A, Bc, Cc, D, chunk=64)[0].sum()
+
+    assert np.isfinite(np.asarray(jax.grad(loss)(dt))).all()
+
+
 def test_decode_continues_prefill_state():
     B, S, H, P, G, N = 2, 48, 3, 8, 1, 16
     x, dt, A, Bc, Cc, D = rand_inputs(jax.random.PRNGKey(1), B, S, H, P, G, N)
