@@ -51,6 +51,7 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
 
+from repro import obs  # noqa: E402
 from repro.ckpt import CheckpointManager, CheckpointPolicy, reshard_restore  # noqa: E402
 from repro.ckpt.manager import flatten_tree  # noqa: E402
 from repro.configs import get_config, get_smoke_config  # noqa: E402
@@ -65,34 +66,26 @@ from repro.train import TrainConfig, make_train_step  # noqa: E402
 
 
 class Clock:
-    """Per-phase wall seconds plus the backend-compile seconds and
-    persistent-cache hits JAX reports while the phase runs."""
+    """Per-phase wall seconds plus the backend-compile seconds (loads from
+    the persistent cache included) and persistent-cache hits that the
+    program's compile counter (``repro.obs``) sees while the phase runs."""
 
     def __init__(self):
-        self.compile_s = 0.0
-        self.cache_hits = 0
-        self.total_compile_s = 0.0
+        self.start = obs.compiles()
 
-        def on_duration(event, duration, **_):
-            if event == "/jax/core/compile/backend_compile_duration":
-                self.compile_s += duration
-                self.total_compile_s += duration
-
-        def on_event(event, **_):
-            if event == "/jax/compilation_cache/cache_hits":
-                self.cache_hits += 1
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-        jax.monitoring.register_event_listener(on_event)
+    @property
+    def total_compile_s(self) -> float:
+        return (obs.compiles() - self.start).compile_s
 
     @contextlib.contextmanager
     def phase(self, name: str):
-        self.compile_s, self.cache_hits = 0.0, 0
+        before = obs.compiles()
         t0 = time.perf_counter()
         yield
+        c = obs.compiles() - before
         print(f"[smoke] phase {name}: {time.perf_counter() - t0:.2f} s wall, "
-              f"{self.compile_s:.2f} s compile "
-              f"({self.cache_hits} persistent-cache hits)", flush=True)
+              f"{c.compile_s:.2f} s compile "
+              f"({c.cache_hits} persistent-cache hits)", flush=True)
 
 
 def check(cond: bool, what: str) -> None:

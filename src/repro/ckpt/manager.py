@@ -52,6 +52,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..core import (BuildReport, Instruction, LayerStore, PassiveRegistry,
                     RelayNode, StructureChangeError, diff_image,
                     fingerprint_tree, fingerprint_tree_packed,
@@ -231,6 +232,7 @@ class CheckpointManager:
         self.last_publish_error: Optional[str] = None
         self._pool = ThreadPoolExecutor(max_workers=1)
         self._pending: Optional[Future] = None
+        self._pending_step: Optional[int] = None
         self._last_fps: Dict[str, np.ndarray] = {}
         self.last_report: Optional[BuildReport] = None
 
@@ -271,24 +273,34 @@ class CheckpointManager:
 
     def wait(self) -> Optional[BuildReport]:
         if self._pending is not None:
-            self.last_report = self._pending.result()
+            with obs.span("ckpt.wait", step=self._pending_step):
+                self.last_report = self._pending.result()
             self._pending = None
         return self.last_report
 
     def save(self, step: int, params, opt_state) -> BuildReport:
         """Dispatches to full or incremental save per policy."""
-        self.wait()
-        payloads = self._payloads(params, opt_state, step)
-        if self.policy.incremental and self.latest_step() is not None:
-            fn = self._save_incremental
-        else:
-            fn = self._save_full
-        if self.policy.async_write:
-            self._pending = self._pool.submit(fn, step, payloads)
-            return BuildReport()        # async: report available at wait()
-        report = fn(step, payloads)
+        with obs.span("ckpt.save", step=step) as call:
+            self.wait()
+            payloads = self._payloads(params, opt_state, step)
+            if self.policy.incremental and self.latest_step() is not None:
+                fn = self._save_incremental
+            else:
+                fn = self._save_full
+            if self.policy.async_write:
+                self._pending = self._pool.submit(self._write, call.id, fn,
+                                                  step, payloads)
+                self._pending_step = step
+                return BuildReport()    # async: report available at wait()
+            report = self._write(call.id, fn, step, payloads)
         self.last_report = report
         return report
+
+    @staticmethod
+    def _write(parent: int, fn, step: int, payloads) -> BuildReport:
+        """The save's write, on the writer thread when it is async."""
+        with obs.span("ckpt.write", parent=parent, step=step):
+            return fn(step, payloads)
 
     def _compute_fps(self, payloads: Dict[str, Dict[str, np.ndarray]],
                      stats: dict) -> Dict[str, np.ndarray]:
@@ -341,17 +353,22 @@ class CheckpointManager:
         fsync of the batch to that commit point), with per-layer cost
         attribution in ``BuildReport.per_layer``."""
         prev = self.latest_step()
-        manifest, _ = self.store.read_image(self.image, self.tag_of(prev))
         stats: dict = {}
         new_fps: Dict[str, np.ndarray] = {}
-        if self.policy.use_fingerprints:
-            new_fps = self._compute_fps(payloads, stats)
-        layers = [self.store.read_layer(lid) for lid in manifest.layer_ids]
-        if self.policy.use_fingerprints:
-            diffs = diff_image(layers, payloads,
-                               old_fps=self._last_fps, new_fps=new_fps)
-        else:
-            diffs = diff_image(layers, payloads)
+        with obs.span("ckpt.diff") as span:
+            manifest, _ = self.store.read_image(self.image,
+                                                self.tag_of(prev))
+            if self.policy.use_fingerprints:
+                new_fps = self._compute_fps(payloads, stats)
+            layers = [self.store.read_layer(lid)
+                      for lid in manifest.layer_ids]
+            counts: dict = {}
+            if self.policy.use_fingerprints:
+                diffs = diff_image(layers, payloads, old_fps=self._last_fps,
+                                   new_fps=new_fps, stats=counts)
+            else:
+                diffs = diff_image(layers, payloads, stats=counts)
+            span.count(**counts)
         try:
             # one batched transaction under the POLICY's durability mode
             # (batch = one deferred fsync flush at the manifest commit)
@@ -376,7 +393,8 @@ class CheckpointManager:
         """Retention (see ``prune_steps``). Runs post-commit on the save
         thread, so no batch transaction is open; LayerStore.gc additionally
         refuses to sweep anything still dirty in an open one."""
-        prune_steps(self.store, self.image, self.policy.keep)
+        with obs.span("ckpt.retention"):
+            prune_steps(self.store, self.image, self.policy.keep)
 
     def _publish(self) -> None:
         """Advertise the just-committed head in the passive bundle
@@ -389,23 +407,24 @@ class CheckpointManager:
         treat as a fall-back signal)."""
         if self.registry is None:
             return
-        try:
-            steps = sorted(s for t in self.store.list_tags(self.image)
-                           if (s := step_of_tag(t)) is not None)
-            if not steps:
-                return
-            froms = [self.tag_of(steps[-1 - span])
-                     for span in self.policy.publish_spans
-                     if span < len(steps)]
-            self.last_publish = self.registry.publish_image(
-                self.store, self.image, self.tag_of(steps[-1]),
-                from_tags=froms)
-            self.last_publish_error = None
-        except CrashInjected:
-            raise           # the saver process dying is not "a dead
-            # object store" — best-effort must not swallow the crash
-        except Exception as e:  # noqa: BLE001
-            self.last_publish_error = f"{type(e).__name__}: {e}"
+        with obs.span("ckpt.publish"):
+            try:
+                steps = sorted(s for t in self.store.list_tags(self.image)
+                               if (s := step_of_tag(t)) is not None)
+                if not steps:
+                    return
+                froms = [self.tag_of(steps[-1 - span])
+                         for span in self.policy.publish_spans
+                         if span < len(steps)]
+                self.last_publish = self.registry.publish_image(
+                    self.store, self.image, self.tag_of(steps[-1]),
+                    from_tags=froms)
+                self.last_publish_error = None
+            except CrashInjected:
+                raise           # the saver process dying is not "a dead
+                # object store" — best-effort must not swallow the crash
+            except Exception as e:  # noqa: BLE001
+                self.last_publish_error = f"{type(e).__name__}: {e}"
 
     # --------------------------------------------------------- replication
     def replicate(self, remote=None, step: Optional[int] = None,

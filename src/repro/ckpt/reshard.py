@@ -16,6 +16,7 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import obs
 from .manager import CheckpointManager
 
 
@@ -23,20 +24,23 @@ def reshard_restore(mgr: CheckpointManager, mesh: Mesh, param_spec_tree,
                     opt_spec_tree=None, step: Optional[int] = None):
     """Restore + place: returns (params, opt_state, step) with leaves
     device_put against the given mesh/specs."""
-    out = mgr.restore(step)
-    if out is None:
-        return None
-    params, opt_state, saved_step = out
+    with obs.span("ckpt.restore"):
+        out = mgr.restore(step)
+        if out is None:
+            return None
+        params, opt_state, saved_step = out
 
-    def place(tree, specs):
-        if specs is None:
-            return jax.tree.map(jax.device_put, tree)
-        return jax.tree.map(
-            lambda a, s: jax.device_put(
-                a, NamedSharding(mesh, s if s is not None else P())),
-            tree, specs)
+        def place(tree, specs):
+            if specs is None:
+                return jax.tree.map(jax.device_put, tree)
+            return jax.tree.map(
+                lambda a, s: jax.device_put(
+                    a, NamedSharding(mesh, s if s is not None else P())),
+                tree, specs)
 
-    params = place(params, param_spec_tree)
-    if opt_spec_tree is not None:
-        opt_state = place(opt_state, opt_spec_tree)
+        # the transfers are dispatched here and finish asynchronously
+        with obs.span("ckpt.place"):
+            params = place(params, param_spec_tree)
+            if opt_spec_tree is not None:
+                opt_state = place(opt_state, opt_spec_tree)
     return params, opt_state, saved_step

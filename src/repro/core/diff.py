@@ -48,6 +48,8 @@ class LayerDiff:
     removed: List[str] = field(default_factory=list)
     chunks_prefiltered: int = 0       # chunks skipped by the fingerprint
                                       # prefilter (no serialize, no SHA)
+    chunks_compared: int = 0          # chunks serialized and SHA'd
+    bytes_hashed: int = 0
 
     @property
     def is_empty(self) -> bool:
@@ -67,6 +69,8 @@ def _host_compare_tensor(rec, name: str, arr, diff: LayerDiff) -> None:
     (the non-prefiltered compare, shared by both diff paths)."""
     data = tensor_to_bytes(arr)
     pieces = list(iter_chunks(data, rec.chunk_bytes))
+    diff.chunks_compared += len(pieces)
+    diff.bytes_hashed += len(data)
     for i, h in enumerate(hash_chunks(pieces)):
         if h != rec.chunks[i]:
             fp = fingerprint_chunk_bytes_ref(
@@ -140,6 +144,8 @@ def diff_layer_fingerprint(layer: LayerDescriptor,
             continue
         idxs = [int(i) for i in changed.tolist()]
         pieces = [tensor_chunk_bytes(arr, i, rec.chunk_bytes) for i in idxs]
+        diff.chunks_compared += len(pieces)
+        diff.bytes_hashed += sum(len(p) for p in pieces)
         for i, piece, h in zip(idxs, pieces, hash_chunks(pieces)):
             if h != rec.chunks[i]:
                 # new fingerprint comes free from the already-computed table
@@ -239,12 +245,16 @@ def diff_image(layers: Sequence[LayerDescriptor],
                payloads: Dict[str, Dict[str, np.ndarray]],
                old_fps: Optional[Dict[str, np.ndarray]] = None,
                new_fps: Optional[Dict[str, np.ndarray]] = None,
+               stats: Optional[dict] = None,
                ) -> Dict[str, LayerDiff]:
     """C1 over a whole image: one non-empty LayerDiff per targeted content
     layer, keyed by layer_id — the input unit of ``inject_image_multi``.
     Passing both fingerprint tables switches every layer to the prefiltered
-    detector; otherwise the host SHA compare runs."""
+    detector; otherwise the host SHA compare runs. ``stats``, when given,
+    gets the whole image's ``bytes_hashed``, ``chunks_compared`` and
+    ``chunks_changed``."""
     diffs: Dict[str, LayerDiff] = {}
+    totals = {"bytes_hashed": 0, "chunks_compared": 0, "chunks_changed": 0}
     for layer in layers:
         if layer.empty:
             continue
@@ -256,6 +266,11 @@ def diff_image(layers: Sequence[LayerDescriptor],
                                        old_fps, new_fps)
         else:
             d = diff_layer_host(layer, payloads[key])
+        totals["bytes_hashed"] += d.bytes_hashed
+        totals["chunks_compared"] += d.chunks_compared
+        totals["chunks_changed"] += len(d.edits)
         if not d.is_empty:
             diffs[layer.layer_id] = d
+    if stats is not None:
+        stats.update(totals)
     return diffs

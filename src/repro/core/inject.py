@@ -49,11 +49,11 @@ same pipeline under the store's own durability mode.
 from __future__ import annotations
 
 import contextlib
-import time
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import obs
 from .chunker import TensorRecord
 from .diff import LayerDiff, diff_image
 from .manifest import (ImageConfig, LayerDescriptor, Manifest, chain_checksum,
@@ -168,8 +168,17 @@ def inject_image_multi(store: LayerStore,
     "batch" (default: one concurrent fsync flush at the commit point),
     "full", or None to keep the store's own mode.
     """
+    with obs.span("store.inject") as span:
+        manifest, config, report = _inject_multi(store, name, tag, new_tag,
+                                                 diffs, providers, durability)
+    report.wall_seconds = span.seconds
+    return manifest, config, report
+
+
+def _inject_multi(store: LayerStore, name: str, tag: str, new_tag: str,
+                  diffs: Dict[str, LayerDiff], providers, durability
+                  ) -> Tuple[Manifest, ImageConfig, BuildReport]:
     report = BuildReport()
-    t0 = time.perf_counter()
     fsyncs0, commits0 = store.fsyncs, store.commits
     manifest, config = store.read_image(name, tag)
     layers = [store.read_layer(lid) for lid in manifest.layer_ids]
@@ -213,71 +222,73 @@ def inject_image_multi(store: LayerStore,
     with _durability_scope(store, durability):
         # Phase A — C4+C2: clone every targeted layer up front and write
         # only the changed chunk blobs into the clones.
-        clones: Dict[str, LayerDescriptor] = {}
-        for lid, diff in live.items():
-            entry = report.layer_entry(lid)
-            chunks0, bytes0 = report.chunks_written, report.bytes_serialized
-            clones[lid] = apply_edits(store, clone_layer(by_id[lid]), diff,
-                                      report)
-            entry["chunks_written"] += report.chunks_written - chunks0
-            entry["bytes_written"] += report.bytes_serialized - bytes0
+        with obs.span("store.write_chunks"):
+            clones: Dict[str, LayerDescriptor] = {}
+            for lid, diff in live.items():
+                entry = report.layer_entry(lid)
+                chunks0, bytes0 = report.chunks_written, report.bytes_serialized
+                clones[lid] = apply_edits(store, clone_layer(by_id[lid]), diff,
+                                          report)
+                entry["chunks_written"] += report.chunks_written - chunks0
+                entry["bytes_written"] += report.bytes_serialized - bytes0
 
         # Phase B — C3: the single downstream re-key walk, consuming the
         # pre-resolved derivation plan (rederive_ids). ``delta`` records
         # this commit's replication unit (core.delta): old->new layer maps
         # by change kind plus the chunk ids written — what a delta push of
         # this commit has to carry.
-        report.rekey_walks += 1
-        delta = {"base": [name, tag], "injected": {}, "rederived": {},
-                 "rekeyed": {}}
-        delta_chunks = {e.new_hash for d in live.values() for e in d.edits}
-        new_layers: List[LayerDescriptor] = []
-        parent_chain: Optional[str] = None
-        dirty = False   # once any upstream id changed, downstream re-keys
-        for layer in layers:
-            ins = layer.instruction
-            clone = clones.get(layer.layer_id)
-            if clone is not None:
-                clone.chain = chain_checksum(parent_chain, clone.checksum,
-                                             ins.text)
-                store.write_layer(clone)
-                new_layers.append(clone)
-                delta["injected"][clone.layer_id] = layer.layer_id
-                dirty = True
-            elif layer.layer_id in rederive_ids:
-                # Scenario-4: a derived layer re-runs its derivation — once
-                # per batch, no matter how many upstream injections hit it.
-                entry = report.layer_entry(layer.layer_id)
-                chunks0 = report.chunks_written
-                bytes0 = report.bytes_serialized
-                payload = providers[ins.arg]()
-                report.derivations_run += 1
-                rebuilt = store.build_content_layer(
-                    ins, payload, parent_chain, report,
-                    family=layer.family, version=layer.version + 1)
-                entry["rederived"] += 1
-                entry["chunks_written"] += report.chunks_written - chunks0
-                entry["bytes_written"] += report.bytes_serialized - bytes0
-                new_layers.append(rebuilt)
-                delta["rederived"][rebuilt.layer_id] = layer.layer_id
-                delta_chunks.update(h for rec in rebuilt.records
-                                    for h in rec.chunks)
-                dirty = True
-            elif dirty:
-                # Downstream of a change: RE-KEY only (chain checksum),
-                # never re-serialize — Docker's fall-through replaced.
-                rekeyed = clone_layer(layer)
-                rekeyed.chain = chain_checksum(parent_chain,
-                                               rekeyed.checksum, ins.text)
-                store.write_layer(rekeyed)
-                new_layers.append(rekeyed)
-                delta["rekeyed"][rekeyed.layer_id] = layer.layer_id
-                report.layers_rekeyed += 1
-                report.layer_entry(layer.layer_id)["rekeyed"] += 1
-            else:
-                new_layers.append(layer)
-                report.layers_cached += 1
-            parent_chain = new_layers[-1].chain
+        with obs.span("store.rekey"):
+            report.rekey_walks += 1
+            delta = {"base": [name, tag], "injected": {}, "rederived": {},
+                     "rekeyed": {}}
+            delta_chunks = {e.new_hash for d in live.values() for e in d.edits}
+            new_layers: List[LayerDescriptor] = []
+            parent_chain: Optional[str] = None
+            dirty = False   # once any upstream id changed, downstream re-keys
+            for layer in layers:
+                ins = layer.instruction
+                clone = clones.get(layer.layer_id)
+                if clone is not None:
+                    clone.chain = chain_checksum(parent_chain, clone.checksum,
+                                                 ins.text)
+                    store.write_layer(clone)
+                    new_layers.append(clone)
+                    delta["injected"][clone.layer_id] = layer.layer_id
+                    dirty = True
+                elif layer.layer_id in rederive_ids:
+                    # Scenario-4: a derived layer re-runs its derivation — once
+                    # per batch, no matter how many upstream injections hit it.
+                    entry = report.layer_entry(layer.layer_id)
+                    chunks0 = report.chunks_written
+                    bytes0 = report.bytes_serialized
+                    payload = providers[ins.arg]()
+                    report.derivations_run += 1
+                    rebuilt = store.build_content_layer(
+                        ins, payload, parent_chain, report,
+                        family=layer.family, version=layer.version + 1)
+                    entry["rederived"] += 1
+                    entry["chunks_written"] += report.chunks_written - chunks0
+                    entry["bytes_written"] += report.bytes_serialized - bytes0
+                    new_layers.append(rebuilt)
+                    delta["rederived"][rebuilt.layer_id] = layer.layer_id
+                    delta_chunks.update(h for rec in rebuilt.records
+                                        for h in rec.chunks)
+                    dirty = True
+                elif dirty:
+                    # Downstream of a change: RE-KEY only (chain checksum),
+                    # never re-serialize — Docker's fall-through replaced.
+                    rekeyed = clone_layer(layer)
+                    rekeyed.chain = chain_checksum(parent_chain,
+                                                   rekeyed.checksum, ins.text)
+                    store.write_layer(rekeyed)
+                    new_layers.append(rekeyed)
+                    delta["rekeyed"][rekeyed.layer_id] = layer.layer_id
+                    report.layers_rekeyed += 1
+                    report.layer_entry(layer.layer_id)["rekeyed"] += 1
+                else:
+                    new_layers.append(layer)
+                    report.layers_cached += 1
+                parent_chain = new_layers[-1].chain
 
         # Phase C — ONE manifest/config commit (the crash-safety point).
         # History is capped: the config is copied forward and re-fsynced on
@@ -311,7 +322,6 @@ def inject_image_multi(store: LayerStore,
     report.manifest_commits = store.commits - commits0
     report.chunks_prefiltered = sum(d.chunks_prefiltered
                                     for d in diffs.values())
-    report.wall_seconds = time.perf_counter() - t0
     return new_manifest, new_config, report
 
 

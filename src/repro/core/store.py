@@ -45,6 +45,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..ft.faults import CrashInjected, fault_point
 from ..ft.scrub import (ScrubFinding, ScrubReport, clear_cursor,
                         load_cursor, save_cursor)
@@ -534,13 +535,16 @@ class LayerStore:
         # Commit point: flush any deferred (durability="batch") blob/layer
         # writes before the manifest becomes visible, then write config +
         # manifest fully synced regardless of durability mode.
-        self.sync_for_commit()
-        _atomic_write(os.path.join(d, f"{config.config_id}.json"),
-                      dumps(config.to_json()).encode())
-        # Manifest rename is the commit point.
-        _atomic_write(os.path.join(d, f"{manifest.tag}.json"),
-                      dumps(manifest.to_json()).encode())
-        self.fsyncs += 2
+        with obs.span("store.commit") as span:
+            fsyncs0 = self.fsyncs
+            self.sync_for_commit()
+            _atomic_write(os.path.join(d, f"{config.config_id}.json"),
+                          dumps(config.to_json()).encode())
+            # Manifest rename is the commit point.
+            _atomic_write(os.path.join(d, f"{manifest.tag}.json"),
+                          dumps(manifest.to_json()).encode())
+            self.fsyncs += 2
+            span.count(fsyncs=self.fsyncs - fsyncs0)
         self.commits += 1
         self._tags_cache.pop(manifest.name, None)
         self._holdings_apply_commit(manifest)
@@ -902,8 +906,18 @@ class LayerStore:
         *derivation* — it is re-executed on every rebuild, which is exactly
         the fall-through cost the paper attacks.
         """
+        with obs.span("store.build") as span:
+            manifest, config, report = self._build_image(
+                name, tag, instructions, providers, parent, arch)
+        report.wall_seconds = span.seconds
+        return manifest, config, report
+
+    def _build_image(self, name: str, tag: str,
+                     instructions: Sequence[Instruction],
+                     providers: Dict[str, Callable[[], Dict[str, np.ndarray]]],
+                     parent: Optional[Tuple[str, str]], arch: str
+                     ) -> Tuple[Manifest, ImageConfig, BuildReport]:
         report = BuildReport()
-        t0 = time.perf_counter()
         fsyncs0, commits0 = self.fsyncs, self.commits
         parent_layers: List[LayerDescriptor] = []
         if parent is not None and self.has_image(*parent):
@@ -982,7 +996,6 @@ class LayerStore:
         self.write_image(manifest, config)
         report.fsyncs = self.fsyncs - fsyncs0
         report.manifest_commits = self.commits - commits0
-        report.wall_seconds = time.perf_counter() - t0
         return manifest, config, report
 
     # ------------------------------------------------------------- load API
@@ -996,16 +1009,21 @@ class LayerStore:
         restricts assembly to those tensors (the sparse-refresh path:
         O(changed tensors) of blob reads instead of O(image)); None loads
         everything."""
-        manifest, _ = self.read_image(name, tag)
-        want = None if names is None else set(names)
-        out: Dict[str, np.ndarray] = {}
-        for lid in manifest.layer_ids:
-            layer = self.read_layer(lid)
-            if layer.empty:
-                continue
-            for r in layer.records:
-                if want is None or r.name in want:
-                    out[r.name] = assemble_tensor(r, self.read_blob)
+        with obs.span("store.load") as span:
+            manifest, _ = self.read_image(name, tag)
+            want = None if names is None else set(names)
+            out: Dict[str, np.ndarray] = {}
+            blobs = 0
+            for lid in manifest.layer_ids:
+                layer = self.read_layer(lid)
+                if layer.empty:
+                    continue
+                for r in layer.records:
+                    if want is None or r.name in want:
+                        out[r.name] = assemble_tensor(r, self.read_blob)
+                        blobs += len(r.chunks)
+            span.count(blobs=blobs,
+                       bytes=sum(a.nbytes for a in out.values()))
         return out
 
     # ---------------------------------------------------------- verification

@@ -20,6 +20,7 @@ from typing import Any, Dict, Optional, Sequence
 import jax
 import numpy as np
 
+from .. import obs
 from ..ckpt import CheckpointManager, CheckpointPolicy, reshard_restore
 from ..configs import get_config, get_smoke_config
 from ..data import SyntheticTokens, make_global_batch
@@ -45,6 +46,27 @@ class TrainRun:
 
 def _specs(shardings):
     return jax.tree.map(lambda s: s.spec, shardings)
+
+
+def _closing_line(totals: Dict[str, Dict[str, float]]) -> str:
+    """The run in one line, from its spans: the loop's seconds per step
+    outside the saves (the D2H copy and the save call), then per save the
+    seconds of each phase of the save."""
+    def sec(name: str) -> float:
+        return totals.get(name, {}).get("seconds", 0.0)
+
+    steps = int(totals.get("train.loop", {}).get("steps", 0))
+    outside = sec("train.loop") - sec("ckpt.d2h") - sec("ckpt.save")
+    line = f"[train] {steps} steps, {outside / max(steps, 1):.3f} s/step " \
+        "outside saves"
+    saves = totals.get("ckpt.save", {}).get("n", 0)
+    if saves:
+        line += f"; per save ({saves}): " + ", ".join(
+            f"{label} {sec(name) / saves:.2f} s" for label, name in (
+                ("d2h", "ckpt.d2h"), ("wait", "ckpt.wait"),
+                ("diff", "ckpt.diff"), ("write", "ckpt.write"),
+                ("commit", "store.commit")))
+    return line
 
 
 def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
@@ -81,8 +103,10 @@ def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
                              async_write=True))
 
     ds = SyntheticTokens(cfg.vocab, batch=args.batch, seq=args.seq)
+    t_start = time.perf_counter()
     with jax.set_mesh(mesh):
-        bundle = make_train_step(cfg, tcfg, mesh, args.batch, args.seq)
+        with obs.span("train.build") as build:
+            bundle = make_train_step(cfg, tcfg, mesh, args.batch, args.seq)
         # state is created (or restored) straight into the step's
         # shardings: nothing lands whole on one device first
         p_sh, o_sh = bundle.in_shardings[:2]
@@ -99,34 +123,41 @@ def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
         wd = Watchdog(args.watchdog_s, lambda: print("[watchdog] step hung")) \
             if args.watchdog_s > 0 else None
         metrics = None
-        t0 = time.perf_counter()
-        for s in range(start_step, args.steps):
-            host_batch = ds.batch_at(s)
-            batch = make_global_batch(
-                mesh, {k: v for k, v in
-                       zip(("tokens", "labels", "mask"),
-                           (bundle.in_shardings[2]["tokens"].spec,
-                            bundle.in_shardings[2]["labels"].spec,
-                            bundle.in_shardings[2]["mask"].spec))},
-                host_batch)
-            if wd:
-                wd.arm()
-            params, opt, metrics = bundle.fn(params, opt, batch)
-            if wd:
-                wd.disarm()
-            if (s + 1) % max(1, args.steps // 20) == 0 or s == start_step:
-                print(f"[train] step {s + 1}: loss={float(metrics['loss']):.4f} "
-                      f"lr={float(metrics['lr']):.2e} "
-                      f"gnorm={float(metrics['grad_norm']):.3f}")
-            if mgr and (s + 1) % args.ckpt_every == 0:
-                mgr.save(s + 1, jax.tree.map(np.asarray, params),
-                         jax.tree.map(np.asarray, opt))
+        bspec = {k: bundle.in_shardings[2][k].spec
+                 for k in ("tokens", "labels", "mask")}
+        with obs.span("train.loop") as loop:
+            loop.count(steps=args.steps - start_step)
+            for s in range(start_step, args.steps):
+                with jax.profiler.StepTraceAnnotation("train", step_num=s):
+                    batch = make_global_batch(mesh, bspec, ds.batch_at(s))
+                    if wd:
+                        wd.arm()
+                    before = obs.compiles() if s == start_step else None
+                    params, opt, metrics = bundle.fn(params, opt, batch)
+                    if before is not None:
+                        # the first call traces, lowers and compiles the
+                        # step (or loads it from the persistent cache)
+                        c = obs.compiles() - before
+                        build.count(trace_s=c.trace_s, lower_s=c.lower_s,
+                                    compile_s=c.compile_s,
+                                    compiles=c.compiles)
+                    if wd:
+                        wd.disarm()
+                    if (s + 1) % max(1, args.steps // 20) == 0 or \
+                            s == start_step:
+                        print(f"[train] step {s + 1}: "
+                              f"loss={float(metrics['loss']):.4f} "
+                              f"lr={float(metrics['lr']):.2e} "
+                              f"gnorm={float(metrics['grad_norm']):.3f}")
+                    if mgr and (s + 1) % args.ckpt_every == 0:
+                        with obs.span("ckpt.d2h", step=s + 1) as d2h:
+                            host = jax.tree.map(np.asarray, (params, opt))
+                            d2h.count(bytes=sum(
+                                a.nbytes for a in jax.tree.leaves(host)))
+                        mgr.save(s + 1, *host)
         if mgr:
             mgr.wait()
-        dt = time.perf_counter() - t0
-        n_steps = args.steps - start_step
-        print(f"[train] {n_steps} steps in {dt:.1f}s "
-              f"({dt / max(n_steps, 1) * 1e3:.1f} ms/step)")
+        print(_closing_line(obs.summary(since=t_start)))
     return TrainRun(params, opt, metrics, mgr)
 
 

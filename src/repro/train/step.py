@@ -94,31 +94,35 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
 
     def step(params, opt_state, batch):
         with activation_ctx(arules):
-            if nmicro == 1:
-                (loss, metrics), grads = jax.value_and_grad(
-                    lambda p: loss_fn(cfg, p, batch), has_aux=True)(params)
-            else:
-                def micro(carry, mb):
-                    acc, _ = carry
-                    (l, m), g = jax.value_and_grad(
-                        lambda p: loss_fn(cfg, p, mb),
-                        has_aux=True)(params)
-                    return (jax.tree.map(jnp.add, acc, g), l), m
+            with jax.named_scope("fwd_bwd"):
+                if nmicro == 1:
+                    (loss, metrics), grads = jax.value_and_grad(
+                        lambda p: loss_fn(cfg, p, batch), has_aux=True)(params)
+                else:
+                    def micro(acc, mb):
+                        (l, m), g = jax.value_and_grad(
+                            lambda p: loss_fn(cfg, p, mb),
+                            has_aux=True)(params)
+                        return jax.tree.map(jnp.add, acc, g), (l, m)
 
-                mbs = jax.tree.map(
-                    lambda a: a.reshape((nmicro, a.shape[0] // nmicro)
-                                        + a.shape[1:]), batch)
-                zero_g = jax.tree.map(
-                    lambda a: jnp.zeros(a.shape, jnp.float32), params)
-                (grads, loss), metrics = jax.lax.scan(
-                    micro, (zero_g, jnp.float32(0)), mbs)
-                grads = jax.tree.map(lambda g: g / nmicro, grads)
-                metrics = jax.tree.map(lambda a: a[-1], metrics)
+                    mbs = jax.tree.map(
+                        lambda a: a.reshape((nmicro, a.shape[0] // nmicro)
+                                            + a.shape[1:]), batch)
+                    zero_g = jax.tree.map(
+                        lambda a: jnp.zeros(a.shape, jnp.float32), params)
+                    grads, (losses, metrics) = jax.lax.scan(micro, zero_g,
+                                                            mbs)
+                    grads = jax.tree.map(lambda g: g / nmicro, grads)
+                    # the step's loss is the mean over its microbatches,
+                    # as its gradient is
+                    loss = losses.mean()
+                    metrics = jax.tree.map(lambda a: a.mean(0), metrics)
             if tcfg.grad_reduce_dtype is not None:
                 rd = jnp.dtype(tcfg.grad_reduce_dtype)
                 grads = jax.tree.map(lambda g: g.astype(rd), grads)
-            new_params, new_opt, stats = apply_update(
-                tcfg.adamw, params, opt_state, grads)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt, stats = apply_update(
+                    tcfg.adamw, params, opt_state, grads)
             out_metrics = {"loss": loss, **metrics, **stats}
         return new_params, new_opt, out_metrics
 
