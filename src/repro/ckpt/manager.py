@@ -28,8 +28,9 @@ tentpole; benchmarks/run.py::bench_incremental_save records it):
      only the (total_chunks, 2) table crosses D2H (``BuildReport.bytes_d2h``).
   2. diff     — fingerprint compare prefilters unchanged chunks
      (``BuildReport.chunks_prefiltered``); only changed chunk *ranges* are
-     serialized (``tensor_chunk_bytes``) and SHA-256'd on the shared hash
-     pool. Leaves stay device-resident until a range is actually touched.
+     SHA-256'd on the shared hash pool, as zero-copy slices of the leaf's
+     byte view (``chunker.tensor_byte_view``). Leaves stay device-resident
+     until a range is actually touched.
   3. store    — all changed layers go through ONE multi-layer injection
      (``core.inject.inject_image_multi``): clone-before-inject per layer,
      a single downstream re-key walk and a single manifest commit per
@@ -66,9 +67,9 @@ def flatten_tree(tree, prefix="") -> Dict[str, np.ndarray]:
     Leaves are kept AS-IS (device arrays stay on device): forcing
     ``np.asarray`` here would pull the entire checkpoint over the host link
     on every save — exactly the O(state) transfer the fingerprint prefilter
-    exists to avoid. Serialization (chunker.tensor_to_bytes /
-    tensor_chunk_bytes) converts lazily, and with fingerprints enabled only
-    the *changed* tensors' bytes ever cross D2H.
+    exists to avoid. The diff's byte view (chunker.tensor_byte_view)
+    converts lazily, and with fingerprints enabled only the *changed*
+    tensors' bytes ever cross D2H.
     """
     out: Dict[str, np.ndarray] = {}
 

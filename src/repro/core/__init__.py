@@ -12,7 +12,7 @@ from .delta import (BundleEntry, BundleIndex, DeltaBundle, DeltaFormatError,
 from .diff import (ChunkEdit, LayerDiff, diff_image, diff_manifests,
                    diff_layer_fingerprint, diff_layer_host,
                    diff_tensor_records, locate_changed_layers)
-from .fingerprint import (chunk_geometry, fingerprint_chunk_bytes_ref,
+from .fingerprint import (chunk_geometry, fingerprint_chunk_bytes,
                           fingerprint_chunks, fingerprint_chunks_ref,
                           fingerprint_tree, fingerprint_tree_packed,
                           fingerprint_tree_ref, tree_pack_index)
@@ -40,7 +40,7 @@ __all__ = [
     "ChunkEdit", "LayerDiff", "diff_image",
     "diff_layer_fingerprint", "diff_layer_host", "diff_tensor_records",
     "locate_changed_layers",
-    "chunk_geometry", "fingerprint_chunk_bytes_ref", "fingerprint_chunks",
+    "chunk_geometry", "fingerprint_chunk_bytes", "fingerprint_chunks",
     "fingerprint_chunks_ref", "fingerprint_tree", "fingerprint_tree_packed",
     "fingerprint_tree_ref", "tree_pack_index",
     "StructureChangeError", "apply_edits", "clone_layer", "inject_image",

@@ -6,17 +6,21 @@ the analogue of files inside a Docker ``layer.tar``. The chunk boundary is
 what makes the paper's injection O(delta): an edit touching k chunks costs
 k chunk writes + k hashes, independent of layer size.
 
-Hot-path mechanics (the fused save pipeline, see also core/diff.py):
+Hot-path mechanics (the save pipeline, see also core/diff.py):
 
+* ``tensor_byte_view`` is a leaf's serialized bytes as a flat, byte-format
+  ``memoryview`` of the array itself — no copy for a C-contiguous leaf.
+  The host diff slices it into chunks and reads each slice once: SHA-256
+  and, for a changed chunk, its fingerprint sidecar in the same task; the
+  changed slices go to the blob writes as they are.
 * ``iter_chunks`` yields zero-copy ``memoryview`` slices — splitting a
   serialized tensor allocates nothing; bytes are only copied when a chunk
-  is actually written or recorded as an edit.
-* ``hash_chunks`` SHA-256's chunk batches on a shared ``ThreadPoolExecutor``
-  — CPython's hashlib releases the GIL for buffers >= 2 KiB, so hashing a
-  multi-chunk tensor scales across cores.
-* ``tensor_chunk_bytes`` serializes ONE chunk's byte range of a tensor
-  without materializing the whole array — what lets the fingerprint
-  prefilter touch O(changed bytes) instead of O(tensor bytes).
+  is written.
+* ``map_chunks`` runs one task per chunk on a shared ``ThreadPoolExecutor``
+  (``hash_chunks`` is its SHA-256-only form) — CPython's hashlib and
+  numpy's ufuncs release the GIL on large buffers, so a multi-chunk tensor
+  scales across cores; a small batch runs inline.
+* ``tensor_chunk_bytes`` copies ONE chunk's byte range of a tensor.
 """
 from __future__ import annotations
 
@@ -24,7 +28,7 @@ import hashlib
 import os
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
-from typing import Iterator, List, Optional, Sequence, Tuple
+from typing import Callable, Iterator, List, Optional, Sequence, Tuple, TypeVar
 
 import numpy as np
 
@@ -48,18 +52,34 @@ def hash_pool() -> Optional[ThreadPoolExecutor]:
 
     Shared by chunk hashing and the registry's pipelined blob transfer —
     tasks submitted here must hash inline (``sha256_hex``), never via
-    ``hash_chunks``, so the pool cannot deadlock on itself."""
+    ``hash_chunks`` or ``map_chunks``, so the pool cannot deadlock on
+    itself."""
     return _HASH_POOL if _HASH_POOL_WORKERS > 1 else None
 
 
-def hash_chunks(pieces: Sequence) -> List[str]:
-    """SHA-256 a batch of bytes-like chunks, fanning out to the shared pool
-    when the batch is large enough for the GIL release to pay off."""
+T = TypeVar("T")
+
+
+def map_chunks(fn: Callable[[int, memoryview], T], pieces: Sequence
+               ) -> Tuple[List[T], int]:
+    """-> (``[fn(i, piece) for i, piece in enumerate(pieces)]``, workers).
+
+    The tasks fan out to the shared pool when the batch is large enough for
+    the GIL release to pay off; ``workers`` is the pool width that ran
+    them, 1 when they ran inline on the caller thread. ``fn`` runs on the
+    pool, so it hashes inline and never calls ``map_chunks`` or
+    ``hash_chunks``."""
     pieces = list(pieces)
     if len(pieces) > 1 and _HASH_POOL_WORKERS > 1 and \
             sum(len(p) for p in pieces) >= _PARALLEL_MIN_BYTES:
-        return list(_HASH_POOL.map(sha256_hex, pieces))
-    return [sha256_hex(p) for p in pieces]
+        return (list(_HASH_POOL.map(fn, range(len(pieces)), pieces)),
+                _HASH_POOL_WORKERS)
+    return [fn(i, p) for i, p in enumerate(pieces)], 1
+
+
+def hash_chunks(pieces: Sequence) -> List[str]:
+    """SHA-256 a batch of bytes-like chunks (``map_chunks``' pool rule)."""
+    return map_chunks(lambda _, p: sha256_hex(p), pieces)[0]
 
 
 @dataclass(frozen=True)
@@ -121,18 +141,18 @@ def dtype_itemsize(dtype: str) -> int:
     return np.dtype(dtype).itemsize
 
 
-def tensor_to_bytes(arr) -> bytes:
-    """Serialize an array (numpy or jax) to contiguous little-endian bytes.
+def tensor_byte_view(arr) -> memoryview:
+    """A leaf's serialized bytes as a flat, byte-format ``memoryview`` of
+    the array (numpy or jax; bfloat16, bool and every other dtype by their
+    raw bits). No copy for a C-contiguous array; the view keeps the array
+    alive."""
+    a = np.ascontiguousarray(np.asarray(arr)).reshape(-1)
+    return memoryview(a.view(np.uint8))
 
-    bfloat16 is handled by bit-level uint16 view (numpy has no bf16).
-    """
-    a = np.asarray(arr)
-    if a.dtype == np.dtype("V2") or str(arr.dtype) == "bfloat16":
-        # jax bf16 -> numpy via ml_dtypes view; np.asarray on a bf16 jax
-        # array yields a bfloat16 ml_dtypes array; view as uint16 bits.
-        a = np.asarray(arr)
-        a = a.view(np.uint16)
-    return np.ascontiguousarray(a).tobytes()
+
+def tensor_to_bytes(arr) -> bytes:
+    """Serialize an array (numpy or jax) to contiguous little-endian bytes."""
+    return bytes(tensor_byte_view(arr))
 
 
 def bytes_to_tensor(data: bytes, shape: Tuple[int, ...], dtype: str) -> np.ndarray:
@@ -161,19 +181,9 @@ def tensor_chunk_bytes(arr, chunk_idx: int,
                        chunk_bytes: int = DEFAULT_CHUNK_BYTES) -> bytes:
     """Serialize ONLY chunk ``chunk_idx`` of a tensor — byte-identical to
     ``tensor_to_bytes(arr)[chunk_idx*cb:(chunk_idx+1)*cb]`` but copies just
-    that range (itemsize always divides the power-of-two chunk size)."""
-    a = np.asarray(arr)
-    if a.dtype == np.dtype("V2") or str(arr.dtype) == "bfloat16":
-        a = np.asarray(arr).view(np.uint16)
-    itemsize = a.dtype.itemsize
-    if chunk_bytes % itemsize:
-        # pathological chunk size: fall back to the full serialization
-        data = tensor_to_bytes(arr)
-        return bytes(data[chunk_idx * chunk_bytes:(chunk_idx + 1) * chunk_bytes])
-    flat = a.ravel()            # view for contiguous arrays (the norm)
-    epc = chunk_bytes // itemsize
-    seg = flat[chunk_idx * epc:(chunk_idx + 1) * epc]
-    return np.ascontiguousarray(seg).tobytes()
+    that range."""
+    view = tensor_byte_view(arr)
+    return bytes(view[chunk_idx * chunk_bytes:(chunk_idx + 1) * chunk_bytes])
 
 
 def chunk_tensor(name: str, arr, chunk_bytes: int = DEFAULT_CHUNK_BYTES):

@@ -4,14 +4,25 @@ Given a stored layer and a new payload, find exactly which chunks changed.
 Two detectors:
 
 * ``diff_layer_host`` — chunk-granular SHA-256 compare on the host. The
-  direct analogue of the paper's text diff. O(changed-layer bytes) of
-  hashing but zero serialization of unchanged chunks to disk.
+  direct analogue of the paper's text diff. Each chunk is one task on the
+  shared hash pool that reads the chunk once, as a zero-copy slice of the
+  payload's byte view (``chunker.tensor_byte_view``): it SHA-256s the
+  slice and, when the chunk changed and its record carries the
+  ``TensorRecord.fp`` sidecar, fingerprints the same slice
+  (``fingerprint_chunk_bytes``). No serialized copy of a leaf and no copy
+  of a changed chunk is made: an edit's data is the payload's own slice,
+  written to its blob as it is. O(layer bytes) of hashing, O(changed
+  bytes) of fingerprinting, zero serialization of unchanged chunks to
+  disk.
 
 * ``diff_layer_fingerprint`` — TPU adaptation: a 64-bit on-device
   fingerprint per chunk (see core/fingerprint.py and the Pallas kernel) is
   compared against the fingerprints recorded at last save; only chunks whose
   fingerprint changed are pulled to host and SHA'd. The device->host traffic
   is O(16 B x chunks + changed bytes).
+
+An edit's ``data`` is a view of the payload, so the payload must outlive
+the edits (``inject_image_multi``'s providers hold it until it returns).
 """
 from __future__ import annotations
 
@@ -20,9 +31,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .chunker import (TensorRecord, hash_chunks, iter_chunks,
-                      tensor_chunk_bytes, tensor_to_bytes)
-from .fingerprint import fingerprint_chunk_bytes_ref
+from .chunker import (TensorRecord, hash_chunks, iter_chunks, map_chunks,
+                      sha256_hex, tensor_byte_view)
+from .fingerprint import fingerprint_chunk_bytes
 from .manifest import LayerDescriptor
 
 
@@ -31,7 +42,7 @@ class ChunkEdit:
     tensor: str
     index: int          # chunk index within the tensor
     new_hash: str
-    data: bytes
+    data: memoryview    # byte-format view of the new chunk in the payload
     # Fingerprint of the NEW chunk bytes ((xor, sum) int32 pair) when the
     # edited record carries a fingerprint sidecar — lets apply_edits keep
     # ``TensorRecord.fp`` alive across injection so the next build_image
@@ -50,6 +61,9 @@ class LayerDiff:
                                       # prefilter (no serialize, no SHA)
     chunks_compared: int = 0          # chunks serialized and SHA'd
     bytes_hashed: int = 0
+    fp_chunks: int = 0                # changed chunks whose sidecar
+                                      # fingerprint the diff computed
+    hash_workers: int = 1             # widest pool that hashed a tensor
 
     @property
     def is_empty(self) -> bool:
@@ -65,18 +79,25 @@ class LayerDiff:
 
 
 def _host_compare_tensor(rec, name: str, arr, diff: LayerDiff) -> None:
-    """Serialize + SHA every chunk of one tensor and record the edits
-    (the non-prefiltered compare, shared by both diff paths)."""
-    data = tensor_to_bytes(arr)
-    pieces = list(iter_chunks(data, rec.chunk_bytes))
+    """SHA every chunk of one tensor and record the edits, one pool task
+    per chunk (the non-prefiltered compare, shared by both diff paths)."""
+    view = tensor_byte_view(arr)
+    pieces = list(iter_chunks(view, rec.chunk_bytes))
+
+    def compare(i: int, piece: memoryview):
+        h = sha256_hex(piece)
+        if h == rec.chunks[i] or rec.fp is None:
+            return h, None
+        return h, fingerprint_chunk_bytes(piece, rec.dtype, rec.chunk_bytes)
+
+    results, workers = map_chunks(compare, pieces)
     diff.chunks_compared += len(pieces)
-    diff.bytes_hashed += len(data)
-    for i, h in enumerate(hash_chunks(pieces)):
+    diff.bytes_hashed += len(view)
+    diff.hash_workers = max(diff.hash_workers, workers)
+    for i, (h, fp) in enumerate(results):
         if h != rec.chunks[i]:
-            fp = fingerprint_chunk_bytes_ref(
-                pieces[i], rec.dtype, rec.chunk_bytes) \
-                if rec.fp is not None else None
-            diff.edits.append(ChunkEdit(name, i, h, bytes(pieces[i]), fp=fp))
+            diff.fp_chunks += fp is not None
+            diff.edits.append(ChunkEdit(name, i, h, pieces[i], fp=fp))
 
 
 def diff_layer_host(layer: LayerDescriptor,
@@ -105,11 +126,11 @@ def diff_layer_fingerprint(layer: LayerDescriptor,
                            new_fps: Dict[str, np.ndarray]) -> LayerDiff:
     """Fingerprint-prefiltered diff. ``old_fps``/``new_fps`` map tensor name
     -> (n_chunks, 2) int32 fingerprints (from core.fingerprint). Only chunks
-    whose fingerprint changed are serialized + SHA'd — and only the changed
-    chunk RANGES of a tensor are serialized (``tensor_chunk_bytes``), never
-    the whole array. Tensors with no recorded old fingerprint fall back to
-    the host SHA compare. ``diff.chunks_prefiltered`` counts the chunks the
-    prefilter proved unchanged (zero serialize/hash cost).
+    whose fingerprint changed are SHA'd — as zero-copy slices of the
+    tensor's byte view, never a serialized copy of the whole array. Tensors
+    with no recorded old fingerprint fall back to the host SHA compare.
+    ``diff.chunks_prefiltered`` counts the chunks the prefilter proved
+    unchanged (zero serialize/hash cost).
     """
     diff = LayerDiff(layer_id=layer.layer_id)
     by_name = {r.name: r for r in layer.records}
@@ -143,7 +164,9 @@ def diff_layer_fingerprint(layer: LayerDescriptor,
         if changed.size == 0:
             continue
         idxs = [int(i) for i in changed.tolist()]
-        pieces = [tensor_chunk_bytes(arr, i, rec.chunk_bytes) for i in idxs]
+        view = tensor_byte_view(arr)
+        cb = rec.chunk_bytes
+        pieces = [view[i * cb:(i + 1) * cb] for i in idxs]
         diff.chunks_compared += len(pieces)
         diff.bytes_hashed += sum(len(p) for p in pieces)
         for i, piece, h in zip(idxs, pieces, hash_chunks(pieces)):
@@ -251,10 +274,13 @@ def diff_image(layers: Sequence[LayerDescriptor],
     layer, keyed by layer_id — the input unit of ``inject_image_multi``.
     Passing both fingerprint tables switches every layer to the prefiltered
     detector; otherwise the host SHA compare runs. ``stats``, when given,
-    gets the whole image's ``bytes_hashed``, ``chunks_compared`` and
-    ``chunks_changed``."""
+    gets the whole image's ``bytes_hashed``, ``chunks_compared``,
+    ``chunks_changed``, ``fp_chunks`` (changed chunks whose sidecar
+    fingerprint the diff computed) and ``hash_workers`` (the widest pool
+    that hashed a tensor, 1 when all ran inline)."""
     diffs: Dict[str, LayerDiff] = {}
-    totals = {"bytes_hashed": 0, "chunks_compared": 0, "chunks_changed": 0}
+    totals = {"bytes_hashed": 0, "chunks_compared": 0, "chunks_changed": 0,
+              "fp_chunks": 0, "hash_workers": 1}
     for layer in layers:
         if layer.empty:
             continue
@@ -269,6 +295,8 @@ def diff_image(layers: Sequence[LayerDescriptor],
         totals["bytes_hashed"] += d.bytes_hashed
         totals["chunks_compared"] += d.chunks_compared
         totals["chunks_changed"] += len(d.edits)
+        totals["fp_chunks"] += d.fp_chunks
+        totals["hash_workers"] = max(totals["hash_workers"], d.hash_workers)
         if not d.is_empty:
             diffs[layer.layer_id] = d
     if stats is not None:

@@ -293,25 +293,46 @@ def fingerprint_tree_ref(tree, chunk_bytes: int = 1 << 20
             for name, v in tree.items()}
 
 
-def fingerprint_chunk_bytes_ref(data, dtype: str,
-                                chunk_bytes: int = 1 << 20
-                                ) -> Optional[Tuple[int, int]]:
+@functools.lru_cache(maxsize=16)
+def _pos_term(lanes: int) -> np.ndarray:
+    """``pos * C2 + C3`` over one chunk's lane positions (read-only; one
+    per lane width, so a save computes it once per dtype width)."""
+    term = np.arange(lanes, dtype=np.uint32) * _C2 + _C3
+    term.flags.writeable = False
+    return term
+
+
+def fingerprint_chunk_bytes(data, dtype: str, chunk_bytes: int = 1 << 20
+                            ) -> Optional[Tuple[int, int]]:
     """Fingerprint ONE serialized chunk — bit-identical to the row this
     chunk gets in ``fingerprint_chunks_ref`` over the whole tensor (lane
     positions restart at 0 per chunk; a partial final chunk zero-pads to
     the full lane width). Host-side, used to refresh the ``TensorRecord.fp``
     sidecar for injected chunks (only changed chunks ever pay this).
 
+    ``data`` is any bytes-like object and is only read: 64-bit elements
+    are two uint32 lanes, sub-32-bit elements widen to one lane each. The
+    mix runs in place in one lane buffer, with the position term cached.
+
     Returns None for pathological chunk sizes that do not align to the
-    dtype's itemsize (mirroring ``chunker.tensor_chunk_bytes``'s fallback):
-    a mid-tensor chunk then splits elements across chunk boundaries and no
-    per-chunk recompute can match the whole-tensor table — callers drop
-    the sidecar instead of crashing.
+    dtype's itemsize: a mid-tensor chunk then splits elements across chunk
+    boundaries and no per-chunk recompute can match the whole-tensor
+    table — callers drop the sidecar instead of crashing.
     """
-    from .chunker import bytes_to_tensor
-    if chunk_bytes % dtype_itemsize(dtype) or \
-            len(data) % dtype_itemsize(dtype):
+    itemsize = dtype_itemsize(dtype)
+    if chunk_bytes % itemsize or len(data) % itemsize:
         return None
-    arr = bytes_to_tensor(bytes(data), (-1,), dtype)
-    fp = fingerprint_chunks_ref(arr, chunk_bytes)
-    return int(fp[0, 0]), int(fp[0, 1])
+    lane = np.dtype(f"u{min(itemsize, 4)}")
+    u = np.frombuffer(data, dtype=lane)
+    # an empty tensor is one chunk of one zero lane, as in the oracle
+    width = chunk_bytes // lane.itemsize if u.size else 1
+    mixed = np.empty(width, np.uint32)
+    np.multiply(u, _C1, out=mixed[:u.size], dtype=np.uint32)
+    mixed[u.size:] = 0
+    mixed ^= _pos_term(width)
+    mixed ^= mixed >> np.uint32(15)
+    mixed *= _C3
+    fp_xor = np.bitwise_xor.reduce(mixed)
+    fp_sum = np.add.reduce(mixed, dtype=np.uint32)
+    out = np.array([fp_xor, fp_sum], np.uint32).view(np.int32)
+    return int(out[0]), int(out[1])

@@ -130,8 +130,8 @@ def apply_edits(store: LayerStore, layer: LayerDescriptor, diff: LayerDiff,
         if fp is not None:
             new_fp = edit.fp
             if new_fp is None:
-                from .fingerprint import fingerprint_chunk_bytes_ref
-                new_fp = fingerprint_chunk_bytes_ref(
+                from .fingerprint import fingerprint_chunk_bytes
+                new_fp = fingerprint_chunk_bytes(
                     edit.data, rec.dtype, rec.chunk_bytes)
             if new_fp is None:
                 # misaligned chunk size: no per-chunk recompute can match

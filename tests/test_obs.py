@@ -82,6 +82,9 @@ def test_spans_of_one_save_share_its_step(tmp_path):
     diff = next(s for s in mine if s.name == "ckpt.diff")
     assert diff.counts["chunks_changed"] > 0
     assert diff.counts["bytes_hashed"] >= params["w"].nbytes
+    assert diff.counts["hash_workers"] >= 1
+    assert mgr.store.record_fingerprints
+    assert diff.counts["fp_chunks"] == diff.counts["chunks_changed"]
     inject = next(s for s in mine if s.name == "store.inject")
     assert inject.parent == write.id
     assert mgr.last_report.wall_seconds == inject.seconds
