@@ -4,17 +4,21 @@ the device check and the result line.
 A cell of ``BENCHMARK.json`` names a configuration and a traffic mix. The
 harness finds them as ``configs/<config>.json`` and
 ``traffic/<traffic>.json``, the limits of its correctness comparison as
-``limits/<cell>.json``, and each per-layer metric as
-``metrics/<metric>.py``. A later cell or metric is added by adding files.
+``limits/<cell>.json``, each per-layer metric as ``metrics/<metric>.py``
+and the model family a configuration names (its ``family`` key) as
+``families/<family>.py``. A later cell, metric or architecture is added by
+adding files.
 """
 from __future__ import annotations
 
 import contextlib
+import functools
 import importlib.util
 import json
 import os
 import time
 from dataclasses import dataclass, field
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -74,6 +78,14 @@ def find_cell(bench: Dict[str, Any], name: str,
         per_layer=[m for m in bench["per_layer"] if _reports(m, name)])
 
 
+def _exec_file(path: str, prefix: str, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(
+        f"{prefix}_{name.replace('.', '_').replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(metric: str, bench_dir: str = BENCH_DIR
                 ) -> Callable[["RunRecord"], Optional[float]]:
     """``metrics/<metric>.py``'s ``read(record)``: the metric's value, or
@@ -81,12 +93,24 @@ def load_reader(metric: str, bench_dir: str = BENCH_DIR
     path = os.path.join(bench_dir, "metrics", f"{metric}.py")
     if not os.path.exists(path):
         raise BenchError(f"no reader for metric {metric!r}: {path}")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{metric.replace('.', '_').replace('-', '_')}",
-        path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _exec_file(path, "chipbench_metric", metric).read
+
+
+def load_family(name: str, bench_dir: Optional[str] = None) -> ModuleType:
+    """``families/<name>.py``: one model family's ``layout(c)``,
+    ``logits(c, params, tokens, precision)``, ``forward_per_token(c,
+    seq)`` and ``model_config(c)``, and optionally ``leaf_init`` and
+    ``loss_sum`` in place of ``chipbench.reference``'s. ``bench_dir``
+    defaults to this module's ``BENCH_DIR``, read at the call."""
+    path = os.path.join(bench_dir or BENCH_DIR, "families", f"{name}.py")
+    if not os.path.exists(path):
+        raise BenchError(f"no family file for {name!r}: {path}")
+    return _family_module(path, name)
+
+
+@functools.lru_cache(maxsize=None)
+def _family_module(path: str, name: str) -> ModuleType:
+    return _exec_file(path, "chipbench_family", name)
 
 
 def peaks_for(device_kind: str, bench_dir: str = BENCH_DIR

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .harness import CHECKOUT, Spans, rng
+from .harness import CHECKOUT, Spans, load_family, rng
 from .reference import flat, layout
 
 
@@ -35,29 +35,17 @@ def import_program():
 
 
 def model_config(c: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.models.config import ModelConfig
+    """The program's ``ModelConfig`` for a configuration file, built by its
+    family file (``families/<family>.py``)."""
+    return load_family(c["family"]).model_config(c)
+
+
+def shared_fields(c: Dict[str, Any]) -> Dict[str, Any]:
+    """The ``ModelConfig`` fields every family takes alike: the name and
+    the precisions the configuration states."""
     prec = c["precision"]
-    common = dict(name=c["name"], param_dtype=prec["params"],
-                  compute_dtype=prec["compute"], logit_dtype=prec["logits"])
-    if c["family"] == "ssm":
-        s = c["ssm_cfg"]
-        di = s["expand"] * c["d_model"]
-        return ModelConfig(
-            family="ssm", n_layers=c["n_layer"], d_model=c["d_model"],
-            vocab=c["vocab_size"], d_inner=di, ssm_state=s["d_state"],
-            ssm_heads=di // s["headdim"], ssm_groups=s["ngroups"],
-            conv_kernel=s["d_conv"], ssm_chunk=s["chunk_size"],
-            tie_embeddings=c["tie_embeddings"], rms_eps=c["norm_epsilon"],
-            **common)
-    d, h = c["hidden_size"], c["num_attention_heads"]
-    return ModelConfig(
-        family="dense", n_layers=c["num_hidden_layers"], d_model=d,
-        vocab=c["vocab_size"], n_heads=h,
-        n_kv_heads=c["num_key_value_heads"], head_dim=d // h,
-        d_ff=c["intermediate_size"], act="swiglu",
-        rope_theta=c["rope_theta"], rms_eps=c["rms_norm_eps"],
-        tie_embeddings=c["tie_word_embeddings"], **common)
+    return dict(name=c["name"], param_dtype=prec["params"],
+                compute_dtype=prec["compute"], logit_dtype=prec["logits"])
 
 
 def check_layout(c: Dict[str, Any], cfg) -> None:

@@ -1,21 +1,15 @@
-"""Plain references of the two model families, their seeded weights, and
+"""Plain references of the model families, their seeded weights, and
 AdamW: what a cell's ``correct`` is decided against.
 
-Nothing here imports the program. The forward passes follow the
-published descriptions in straightforward ``jax.numpy``, in float32 with
-every matrix product at ``Precision.HIGHEST``:
-
-* ``ssm``: Mamba-2 (arXiv:2405.21060). Per layer: RMSNorm, input
-  projections to z, x, B, C and dt, a depthwise causal convolution of x,
-  B and C followed by SiLU, dt = softplus(dt + dt_bias), A = -exp(A_log),
-  the SSD in its quadratic (dual) form over the whole sequence
-  y_i = sum_{j<=i} (C_i . B_j) exp(sum_{j<k<=i} dt_k A) dt_j x_j + D x_i,
-  then RMSNorm(y * silu(z)) over the inner width, the output projection
-  and the residual. The output head is the transposed embedding.
-* ``dense``: a Llama-architecture decoder (Yi, arXiv:2403.04652). Per
-  layer: RMSNorm, grouped-query attention with rotary embeddings
-  (rotate-half convention, theta from the configuration), causal softmax,
-  RMSNorm, SwiGLU MLP; the output head is its own matrix.
+Nothing here, and nothing in a family's reference, imports the program.
+Each family's forward pass is in ``families/<family>.py`` (found by the
+configuration's ``family`` key, ``harness.load_family``), and follows its
+published description in straightforward ``jax.numpy``, in float32 with
+every matrix product at ``Precision.HIGHEST`` through ``mm``. This module
+keeps what all families share: the seeded weights, the matrix product and
+its control, RMSNorm, rotary embeddings, the decoder frame (embedding,
+stacked layers, final norm, output head), the loss, AdamW and the
+training reference.
 
 The loss is the mean next-token cross-entropy over the configuration's
 vocabulary. The embedding rows above the vocabulary (padding) are never
@@ -40,39 +34,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .harness import seed_words
+from .harness import load_family, seed_words
 
 HIGHEST = jax.lax.Precision.HIGHEST
-F32_LEAVES = ("norm", "gate_norm", "attn_norm", "mlp_norm", "final_norm",
-              "conv_x_b", "conv_B_b", "conv_C_b", "A_log", "D", "dt_bias")
 
 
-# ----------------------------------------------------------------- sizes
-def dims(c: Dict[str, Any]) -> Dict[str, int]:
-    """The sizes the reference uses, from the configuration file."""
-    if c["family"] == "ssm":
-        s = c["ssm_cfg"]
-        d = c["d_model"]
-        di = s["expand"] * d
-        out = dict(L=c["n_layer"], d=d, V=c["vocab_size"], di=di,
-                   N=s["d_state"], G=s["ngroups"], P=s["headdim"],
-                   H=di // s["headdim"], K=s["d_conv"],
-                   eps=c["norm_epsilon"], tied=c["tie_embeddings"])
-    else:
-        d = c["hidden_size"]
-        H = c["num_attention_heads"]
-        out = dict(L=c["num_hidden_layers"], d=d, V=c["vocab_size"],
-                   H=H, KVH=c["num_key_value_heads"], Dh=d // H,
-                   F=c["intermediate_size"], eps=c["rms_norm_eps"],
-                   theta=c["rope_theta"], tied=c["tie_word_embeddings"])
-    m = c.get("pad_vocab_size_multiple", 256)
-    out["Vp"] = -(-out["V"] // m) * m
-    return out
-
-
+# ---------------------------------------------------------------- layout
 def layout(c: Dict[str, Any]) -> Dict[str, Tuple[Tuple[int, ...], str]]:
     """'/'-joined leaf path -> (shape, dtype) of the parameter tree."""
-    z = dims(c)
+    return load_family(c["family"]).layout(c)
+
+
+def padded_vocab(c: Dict[str, Any], vocab: int) -> int:
+    m = c.get("pad_vocab_size_multiple", 256)
+    return -(-vocab // m) * m
+
+
+def decoder_layout(z: Dict[str, Any], blocks: Dict[str, Tuple[int, ...]],
+                   f32_leaves) -> Dict[str, Tuple[Tuple[int, ...], str]]:
+    """The embedding (``Vp`` rows), final norm and, untied, output head,
+    and each of ``blocks`` stacked over ``L`` layers; the leaves named in
+    ``f32_leaves`` are float32, the others bfloat16."""
     L, d = z["L"], z["d"]
     out: Dict[str, Tuple[Tuple[int, ...], str]] = {
         "embed": ((z["Vp"], d), "bfloat16"),
@@ -80,25 +62,9 @@ def layout(c: Dict[str, Any]) -> Dict[str, Tuple[Tuple[int, ...], str]]:
     }
     if not z["tied"]:
         out["lm_head"] = ((d, z["Vp"]), "bfloat16")
-    if c["family"] == "ssm":
-        H, P, G, N, K = z["H"], z["P"], z["G"], z["N"], z["K"]
-        blocks = {
-            "norm": (d,), "w_z": (d, H, P), "w_x": (d, H, P),
-            "w_B": (d, G, N), "w_C": (d, G, N), "w_dt": (d, H),
-            "conv_x_w": (H, P, K), "conv_x_b": (H, P),
-            "conv_B_w": (G, N, K), "conv_B_b": (G, N),
-            "conv_C_w": (G, N, K), "conv_C_b": (G, N),
-            "A_log": (H,), "D": (H,), "dt_bias": (H,),
-            "gate_norm": (H, P), "out_proj": (H, P, d)}
-    else:
-        H, KVH, Dh, F = z["H"], z["KVH"], z["Dh"], z["F"]
-        blocks = {
-            "attn_norm": (d,), "mlp_norm": (d,), "wq": (d, H, Dh),
-            "wk": (d, KVH, Dh), "wv": (d, KVH, Dh), "wo": (H, Dh, d),
-            "w_gate": (d, F), "w_up": (d, F), "w_down": (F, d)}
     for k, shape in blocks.items():
         out[f"blocks/{k}"] = ((L,) + shape,
-                              "float32" if k in F32_LEAVES else "bfloat16")
+                              "float32" if k in f32_leaves else "bfloat16")
     return out
 
 
@@ -125,9 +91,10 @@ def flat(tree: Dict[str, Any], prefix: str = "") -> Dict[str, Any]:
 
 
 # --------------------------------------------------------------- weights
-def _leaf_init(name: str, shape, key, c: Dict[str, Any]) -> jax.Array:
-    """Values of one leaf: fan-in scaled normals for the matrices, and the
-    published Mamba-2 ranges for its decay, step and skip parameters."""
+def leaf_init(name: str, shape, key, c: Dict[str, Any]) -> jax.Array:
+    """Values of one leaf, unless its family file has a ``leaf_init`` of
+    its own: fan-in scaled normals for the matrices, and the published
+    Mamba-2 ranges for its decay, step and skip parameters."""
     base = name.split("/")[-1]
     n = jax.random.normal(key, shape, jnp.float32)
     u = jax.random.uniform(key, shape, jnp.float32)
@@ -157,11 +124,12 @@ def init_weights(c: Dict[str, Any], seed: int) -> Dict[str, Any]:
     call, in the dtypes they are trained and served in."""
     lay = layout(c)
     names = sorted(lay)
+    init = getattr(load_family(c["family"]), "leaf_init", leaf_init)
 
     def make(words):
         key = jax.random.fold_in(jax.random.PRNGKey(words[0]), words[1])
         keys = jax.random.split(key, len(names))
-        return nest({nm: _leaf_init(nm, lay[nm][0], k, c)
+        return nest({nm: init(nm, lay[nm][0], k, c)
                      .astype(jnp.dtype(lay[nm][1]))
                      for nm, k in zip(names, keys)})
 
@@ -186,46 +154,7 @@ def rms(x, w, eps, axes=(-1,)):
     return x * jax.lax.rsqrt(var + eps) * w
 
 
-def _causal_conv(x, w, b):
-    """x: (B, S, *C); w: (*C, K): y_t = b + sum_k w_k x_{t-K+1+k}."""
-    K, S = w.shape[-1], x.shape[1]
-    xp = jnp.pad(x, [(0, 0), (K - 1, 0)] + [(0, 0)] * (x.ndim - 2))
-    return b + sum(xp[:, k:k + S] * w[..., k] for k in range(K))
-
-
-def _ssd(x, dt, A, Bm, Cm, D, prec):
-    """Dual (quadratic) form of the SSD over the whole sequence.
-    x (b,S,H,P), dt (b,S,H), A (H,), Bm/Cm (b,S,G,N), D (H,)."""
-    H, G = x.shape[2], Bm.shape[2]
-    Lc = jnp.cumsum(dt * A, axis=1)                          # (b,S,H)
-    seg = Lc[:, :, None, :] - Lc[:, None, :, :]              # (b,i,j,H)
-    S = x.shape[1]
-    causal = (jnp.arange(S)[:, None] >= jnp.arange(S)[None, :])
-    decay = jnp.exp(jnp.where(causal[None, :, :, None], seg, -jnp.inf))
-    cb = mm("bign,bjgn->bgij", Cm, Bm, prec)
-    cb = jnp.repeat(cb, H // G, axis=1)                      # (b,H,i,j)
-    w = cb * decay.transpose(0, 3, 1, 2) * dt.transpose(0, 2, 1)[:, :, None]
-    y = mm("bhij,bjhp->bihp", w, x, prec)
-    return y + x * D[None, None, :, None]
-
-
-def _ssm_layer(z, p, x, prec):
-    h = rms(x, p["norm"], z["eps"])
-    zg = mm("bsd,dhp->bshp", h, p["w_z"], prec)
-    xs = mm("bsd,dhp->bshp", h, p["w_x"], prec)
-    Bm = mm("bsd,dgn->bsgn", h, p["w_B"], prec)
-    Cm = mm("bsd,dgn->bsgn", h, p["w_C"], prec)
-    dt = mm("bsd,dh->bsh", h, p["w_dt"], prec)
-    xs = jax.nn.silu(_causal_conv(xs, p["conv_x_w"], p["conv_x_b"]))
-    Bm = jax.nn.silu(_causal_conv(Bm, p["conv_B_w"], p["conv_B_b"]))
-    Cm = jax.nn.silu(_causal_conv(Cm, p["conv_C_w"], p["conv_C_b"]))
-    dt = jax.nn.softplus(dt + p["dt_bias"])
-    y = _ssd(xs, dt, -jnp.exp(p["A_log"]), Bm, Cm, p["D"], prec)
-    g = rms(y * jax.nn.silu(zg), p["gate_norm"], z["eps"], axes=(-2, -1))
-    return x + mm("bshp,hpd->bsd", g, p["out_proj"], prec)
-
-
-def _rope(x, theta):
+def rope(x, theta):
     """x: (b, S, H, D); rotate-half pairs (i, i + D/2)."""
     S, D = x.shape[1], x.shape[-1]
     freqs = 1.0 / (theta ** (np.arange(0, D, 2, dtype=np.float64) / D))
@@ -236,30 +165,17 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def _dense_layer(z, p, x, prec):
-    h = rms(x, p["attn_norm"], z["eps"])
-    q = _rope(mm("bsd,dhk->bshk", h, p["wq"], prec), z["theta"])
-    k = _rope(mm("bsd,dhk->bshk", h, p["wk"], prec), z["theta"])
-    v = mm("bsd,dhk->bshk", h, p["wv"], prec)
-    rep = z["H"] // z["KVH"]
-    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
-    s = mm("bqhk,bshk->bhqs", q, k, prec) / math.sqrt(z["Dh"])
-    S = x.shape[1]
-    causal = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-    s = jnp.where(causal[None, None], s, -jnp.inf)
-    o = mm("bhqs,bshk->bqhk", jax.nn.softmax(s, axis=-1), v, prec)
-    x = x + mm("bqhk,hkd->bqd", o, p["wo"], prec)
-    h = rms(x, p["mlp_norm"], z["eps"])
-    u = jax.nn.silu(mm("bsd,df->bsf", h, p["w_gate"], prec)) * \
-        mm("bsd,df->bsf", h, p["w_up"], prec)
-    return x + mm("bsf,fd->bsd", u, p["w_down"], prec)
-
-
 def logits(c: Dict[str, Any], params: Dict[str, Any], tokens,
            precision: str = "f32") -> jax.Array:
     """(b, S) tokens -> (b, S, V) float32 logits over the vocabulary."""
-    z = dims(c)
-    layer = _ssm_layer if c["family"] == "ssm" else _dense_layer
+    return load_family(c["family"]).logits(c, params, tokens, precision)
+
+
+def decoder_logits(z: Dict[str, Any], layer, params: Dict[str, Any], tokens,
+                   precision: str) -> jax.Array:
+    """Embedding, ``layer(z, layer_params, x, precision)`` scanned over the
+    stacked blocks (rematerialised), final RMSNorm and the output head (the
+    transposed embedding where tied), in float32."""
     p = jax.tree.map(lambda a: a.astype(jnp.float32), params)
     x = jnp.take(p["embed"], tokens, axis=0)
 
@@ -273,6 +189,15 @@ def logits(c: Dict[str, Any], params: Dict[str, Any], tokens,
 
 
 def loss_sum(c, params, tokens, labels, precision="f32"):
+    """The summed loss the reference trains on: the family's ``loss_sum``
+    where its file has one, else ``cross_entropy_sum``."""
+    fam = load_family(c["family"])
+    if hasattr(fam, "loss_sum"):
+        return fam.loss_sum(c, params, tokens, labels, precision)
+    return cross_entropy_sum(c, params, tokens, labels, precision)
+
+
+def cross_entropy_sum(c, params, tokens, labels, precision="f32"):
     lg = logits(c, params, tokens, precision)
     lse = jax.nn.logsumexp(lg, axis=-1)
     picked = jnp.take_along_axis(lg, labels[..., None], axis=-1)[..., 0]

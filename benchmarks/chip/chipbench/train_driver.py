@@ -18,6 +18,13 @@ Correct: the program's first three steps (losses, the first gradient as
 Adam's first moment holds it, the change of the float32 weights) against
 the plain reference from the same seed, and the state restored from the
 window's last save against the live state.
+
+Traffic keys (``traffic/<name>.json``): ``batch``, ``seq``, ``ckpt_every``
+(steps per save), ``setup_steps`` (where the window's call resumes),
+``checked_steps`` (the steps compared), ``optimizer`` (AdamW's settings),
+and optionally ``reference_rows``: the rows of each reference gradient
+call, whose float32 activations bound the reference's memory (default 2;
+a long-sequence cell sets 1 to fit one chip).
 """
 from __future__ import annotations
 
@@ -245,7 +252,8 @@ def reference_readings(c, t, seed: int, precision: str = "f32"):
                for s in range(t["checked_steps"])]
     return train_reference(c, t["optimizer"], init_weights(c, seed),
                            [(b["tokens"], b["labels"]) for b in batches],
-                           precision=precision)
+                           precision=precision,
+                           row_block=t.get("reference_rows", 2))
 
 
 def _place(mesh, a, spec):

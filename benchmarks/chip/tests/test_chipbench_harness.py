@@ -87,11 +87,26 @@ def test_run_fails_without_a_tpu():
 
 
 def test_benchmark_file_keys():
+    """Holds for any later addition: the contract's keys, today's names
+    among the metrics, a reader for each per-layer metric, known cells in
+    every ``workloads`` list, and a family file for each configuration."""
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    assert {m["name"] for m in bench["end_to_end"]} == {
-        "train_tokens_per_s", "resume_s", "setup_s"}
-    assert {m["name"] for m in bench["per_layer"]} == {
-        "train_mfu", "train_step_s", "save_stall_s", "save_write_s",
-        "restore_s", "device_idle_share.train"}
+    end_to_end = {m["name"] for m in bench["end_to_end"]}
+    assert {"train_tokens_per_s", "resume_s", "setup_s"} <= end_to_end
+    assert {"train_mfu", "train_step_s", "save_stall_s", "save_write_s",
+            "restore_s", "device_idle_share.train", "save_d2h_s",
+            "ckpt_wait_s", "save_diff_s", "save_commit_s", "restore_read_s",
+            "step_build_s"} <= {m["name"] for m in bench["per_layer"]}
+    for m in bench["per_layer"]:
+        assert os.path.exists(os.path.join(BENCH, "metrics",
+                                           f"{m['name']}.py")), m["name"]
+        assert m["moves"] in end_to_end, m["name"]
+    cells = {w["name"] for w in bench["workloads"]}
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        assert set(m.get("workloads", ())) <= cells, m["name"]
+    for c in bench["configs"]:
+        family = json.load(open(os.path.join(ROOT, c["file"])))["family"]
+        assert os.path.exists(os.path.join(BENCH, "families",
+                                           f"{family}.py")), c["name"]
