@@ -59,6 +59,7 @@ from ..core import (BuildReport, Instruction, LayerStore, PassiveRegistry,
                     fingerprint_tree, fingerprint_tree_packed,
                     inject_image_multi, push_delta, replicate_fanout)
 from ..ft.faults import CrashInjected
+from ..models.model import LAYER_STACKS
 
 
 def flatten_tree(tree, prefix="") -> Dict[str, np.ndarray]:
@@ -254,10 +255,11 @@ class CheckpointManager:
         flat = flatten_tree(params, "params")
         embed = {k: v for k, v in flat.items()
                  if k.startswith("params/embed")}
+        # every per-layer subtree (the leading dense layers too) is a block
         blocks = {k: v for k, v in flat.items()
-                  if k.startswith("params/blocks")}
+                  if (k.split("/") + [""])[1] in LAYER_STACKS}
         head = {k: v for k, v in flat.items()
-                if not k.startswith(("params/embed", "params/blocks"))}
+                if k not in embed and k not in blocks}
         opt = flatten_tree(opt_state, "opt")
         opt["opt/__step__"] = np.asarray([step], np.int32)
         return {"params/embed": embed, "params/blocks": blocks,

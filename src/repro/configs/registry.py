@@ -4,15 +4,15 @@ from __future__ import annotations
 from typing import List
 
 from ..models.config import ModelConfig
-from . import (command_r_35b, gemma_2b, granite_moe_3b, hymba_1_5b,
-               mamba2_130m, minicpm3_4b, mixtral_8x7b, musicgen_medium,
-               pixtral_12b, yi_6b)
+from . import (command_r_35b, deepseek_v2_lite, gemma_2b, granite_moe_3b,
+               hymba_1_5b, mamba2_130m, minicpm3_4b, mixtral_8x7b,
+               musicgen_medium, pixtral_12b, yi_6b)
 
 _MODULES = {
     m.ARCH_ID: m
     for m in (pixtral_12b, mamba2_130m, granite_moe_3b, mixtral_8x7b,
               gemma_2b, command_r_35b, minicpm3_4b, yi_6b,
-              musicgen_medium, hymba_1_5b)
+              musicgen_medium, hymba_1_5b, deepseek_v2_lite)
 }
 
 ARCH_IDS: List[str] = list(_MODULES)

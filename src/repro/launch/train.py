@@ -154,6 +154,16 @@ def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
                             host = jax.tree.map(np.asarray, (params, opt))
                             d2h.count(bytes=sum(
                                 a.nbytes for a in jax.tree.leaves(host)))
+                        if "moe_assignments" in metrics:
+                            # routed-expert counters, summed over the
+                            # MoE layers; the D2H above has synchronised
+                            with obs.span("train.moe", step=s + 1) as moe:
+                                moe.count(
+                                    tokens=args.batch * args.seq,
+                                    assignments_held=float(
+                                        metrics["moe_assignments"]),
+                                    max_expert_load=float(
+                                        metrics["moe_max_load"]))
                         mgr.save(s + 1, *host)
         if mgr:
             mgr.wait()

@@ -25,9 +25,10 @@ import jax.numpy as jnp
 from ..sharding.ctx import constrain
 from .attention import attention, attention_decode
 from .config import ModelConfig
-from .layers import (apply_rope, dense, dense_init, proj_heads, rms_norm,
-                     trunc_normal, unproj_heads)
-from .moe import moe_ffn
+from .layers import (apply_rope, dense, dense_init, gated_mlp, proj_heads,
+                     rms_norm, trunc_normal, unproj_heads, yarn_freqs,
+                     yarn_mscale)
+from .moe import held_experts, moe_ffn, route_topk, seq_balance_loss
 from .ssm import (causal_conv, causal_conv_step, ssd_chunked,
                   ssd_decode_step)
 
@@ -285,32 +286,120 @@ def init_mla_block(cfg: ModelConfig, key) -> Dict:
     d, dt, H = cfg.d_model, cfg.param_dtype, cfg.n_heads
     qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
     nope, rope, vh = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    if qr:
+        q = {"wq_a": dense_init(ks[0], d, qr, dt),
+             "q_norm": jnp.ones((qr,), jnp.float32),
+             "wq_b": _head_init(ks[1], qr, H, nope + rope, dt)}
+    else:
+        q = {"wq": _head_init(ks[0], d, H, nope + rope, dt)}
     return {
         "attn_norm": jnp.ones((d,), jnp.float32),
         "mlp_norm": jnp.ones((d,), jnp.float32),
-        "wq_a": dense_init(ks[0], d, qr, dt),
-        "q_norm": jnp.ones((qr,), jnp.float32),
-        "wq_b": _head_init(ks[1], qr, H, nope + rope, dt),
+        **q,
         "wkv_a": dense_init(ks[2], d, kr + rope, dt),
         "kv_norm": jnp.ones((kr,), jnp.float32),
         "wkv_b": _head_init(ks[3], kr, H, nope + vh, dt),
         "wo": trunc_normal(ks[4], (H, vh, d), (H * vh) ** -0.5, dt),
-        **_mlp_init(cfg, ks[5]),
+        **(_routed_init(cfg, ks[5]) if cfg.is_moe else _mlp_init(cfg, ks[5])),
     }
+
+
+def _routed_init(cfg: ModelConfig, key) -> Dict:
+    """Router over every routed expert, the held experts' SwiGLUs (E, d,
+    f) / (E, f, d), and the shared experts as one SwiGLU of their summed
+    width."""
+    ks = jax.random.split(key, 7)
+    d, dt, E, fe = cfg.d_model, cfg.param_dtype, cfg.n_experts, \
+        cfg.d_ff_expert
+    fs = cfg.n_shared_experts * fe
+
+    def experts(k, a, b):
+        return (jax.random.truncated_normal(k, -2, 2, (E, a, b))
+                * a ** -0.5).astype(dt)
+
+    return {
+        "router": dense_init(ks[0], d, cfg.router_width, jnp.float32),
+        "w_gate": experts(ks[1], d, fe),
+        "w_up": experts(ks[2], d, fe),
+        "w_down": experts(ks[3], fe, d),
+        "shared_gate": dense_init(ks[4], d, fs, dt),
+        "shared_up": dense_init(ks[5], d, fs, dt),
+        "shared_down": dense_init(ks[6], fs, d, dt),
+    }
+
+
+MOE_COUNTERS = ("assignments", "max_load")
+
+
+def aux_zero(cfg: ModelConfig):
+    """What a block of this configuration adds up over its layers: the
+    router's balance loss and, for DeepSeek-V2's routed experts, the held
+    experts' assignments and largest load."""
+    if cfg.family == "mla" and cfg.is_moe:
+        return {k: jnp.float32(0.0) for k in ("balance",) + MOE_COUNTERS}
+    return jnp.float32(0.0)
+
+
+def _routed_ffn(cfg: ModelConfig, p: Dict, h: jax.Array):
+    """h: (B, S, d) -> (routed + shared output, aux as ``aux_zero``)."""
+    B, S, d = h.shape
+    h2 = h.reshape(B * S, d)
+    with jax.named_scope("moe_route"):
+        gate, experts, scores = route_topk(h2, p["router"], cfg.top_k,
+                                           cfg.routed_scaling)
+        balance = seq_balance_loss(scores.reshape(B, S, -1),
+                                   experts.reshape(B, S, -1))
+    with jax.named_scope("moe_experts"):
+        y, sizes = held_experts(h2, gate, experts, p["w_gate"], p["w_up"],
+                                p["w_down"], cfg.expert_offset)
+    with jax.named_scope("moe_shared"):
+        y = y + gated_mlp(h2, p["shared_gate"], p["shared_up"],
+                          p["shared_down"], cfg.act)
+    sizes = sizes.astype(jnp.float32)
+    return y.reshape(B, S, d), {"balance": balance,
+                                "assignments": sizes.sum(),
+                                "max_load": sizes.max()}
+
+
+def _mla_ffn(cfg: ModelConfig, p: Dict, h: jax.Array):
+    if cfg.is_moe:
+        return _routed_ffn(cfg, p, h)
+    return _mlp(cfg, p, h), jnp.float32(0.0)
+
+
+def _mla_rope(cfg: ModelConfig, x: jax.Array, positions: jax.Array):
+    freqs, scale = None, 1.0
+    if cfg.yarn_factor > 1:
+        freqs = yarn_freqs(x.shape[-1], cfg.rope_theta, cfg.yarn_factor,
+                           cfg.yarn_original_len, cfg.yarn_beta_fast,
+                           cfg.yarn_beta_slow)
+        scale = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale) / \
+            yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+    return apply_rope(x, positions, cfg.rope_theta, freqs,
+                      cfg.rope_interleave, scale)
+
+
+def _mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale: 1/sqrt(nope + rope), times YaRN's mscale squared."""
+    m = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) \
+        if cfg.yarn_mscale_all_dim else 1.0
+    return (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5 * m * m
 
 
 def _mla_qkv(cfg: ModelConfig, p: Dict, h: jax.Array, positions: jax.Array):
     """-> q (B,S,H,nope+rope), c_kv (B,S,kr) normed, k_rope (B,S,rope)."""
     nope = cfg.qk_nope_dim
-    qa = rms_norm(dense(h, p["wq_a"]), p["q_norm"], cfg.rms_eps)
-    q = proj_heads(qa, p["wq_b"])
+    if cfg.q_lora_rank:
+        qa = rms_norm(dense(h, p["wq_a"]), p["q_norm"], cfg.rms_eps)
+        q = proj_heads(qa, p["wq_b"])
+    else:
+        q = proj_heads(h, p["wq"])
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rope(cfg, q_rope, positions)
     q = constrain(jnp.concatenate([q_nope, q_rope], axis=-1), "act_q")
     kv_a = dense(h, p["wkv_a"])
     c_kv = rms_norm(kv_a[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.rms_eps)
-    k_rope = apply_rope(kv_a[..., cfg.kv_lora_rank:], positions,
-                        cfg.rope_theta)
+    k_rope = _mla_rope(cfg, kv_a[..., cfg.kv_lora_rank:], positions)
     return q, c_kv, k_rope
 
 
@@ -318,29 +407,30 @@ def apply_mla_block(cfg: ModelConfig, p: Dict, x: jax.Array,
                     positions: jax.Array, collect_cache: bool = False):
     B, S, d = x.shape
     H = cfg.n_heads
-    nope, rope, vh = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    h = rms_norm(x, p["attn_norm"], cfg.rms_eps)
-    q, c_kv, k_rope = _mla_qkv(cfg, p, h, positions)
-    # expand keys/values from the latent (training path)
-    kv = proj_heads(c_kv, p["wkv_b"])                       # (B,S,H,nope+vh)
-    k_nope, v = kv[..., :nope], kv[..., nope:]
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope[:, :, None], (B, S, H, rope))],
-        axis=-1)
-    k = constrain(k, "act_q")
-    o = attention(q, k, constrain(v, "act_q"), causal=True,
-                  window=cfg.window, impl=cfg.attn_impl,
-                  kv_block=cfg.kv_block, q_block=cfg.q_block,
-                  scale=(nope + rope) ** -0.5,
-                  score_dtype=cfg.score_dtype)
-    x = constrain(x + unproj_heads(constrain(o, "act_q"), p["wo"]),
-                  "act_hidden")
+    nope, rope = cfg.qk_nope_dim, cfg.qk_rope_dim
+    with jax.named_scope("mla"):
+        h = rms_norm(x, p["attn_norm"], cfg.rms_eps)
+        q, c_kv, k_rope = _mla_qkv(cfg, p, h, positions)
+        # expand keys/values from the latent (training path)
+        kv = proj_heads(c_kv, p["wkv_b"])                   # (B,S,H,nope+vh)
+        k_nope, v = kv[..., :nope], kv[..., nope:]
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, :, None], (B, S, H, rope))],
+            axis=-1)
+        k = constrain(k, "act_q")
+        o = attention(q, k, constrain(v, "act_q"), causal=True,
+                      window=cfg.window, impl=cfg.attn_impl,
+                      kv_block=cfg.kv_block, q_block=cfg.q_block,
+                      scale=_mla_scale(cfg), score_dtype=cfg.score_dtype)
+        x = constrain(x + unproj_heads(constrain(o, "act_q"), p["wo"]),
+                      "act_hidden")
     h = rms_norm(x, p["mlp_norm"], cfg.rms_eps)
-    x = constrain(x + _mlp(cfg, p, h), "act_hidden")
+    y, aux = _mla_ffn(cfg, p, h)
+    x = constrain(x + y, "act_hidden")
     cache = None
     if collect_cache:
         cache = {"c_kv": c_kv, "k_rope": k_rope}
-    return x, jnp.float32(0.0), cache
+    return x, aux, cache
 
 
 def mla_cache_init(cfg: ModelConfig, batch: int, cache_len: int) -> Dict:
@@ -354,7 +444,7 @@ def decode_mla_block(cfg: ModelConfig, p: Dict, cache: Dict,
     """Absorbed MLA decode: attention runs in latent space; the cache is the
     (kv_lora_rank + rope) latent — MLA's memory advantage."""
     B, d = x_t.shape
-    nope, rope, vh = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    nope = cfg.qk_nope_dim
     h = rms_norm(x_t, p["attn_norm"], cfg.rms_eps)[:, None]     # (B,1,d)
     pos_b = jnp.broadcast_to(pos, (B, 1))
     q, c_kv, k_rope = _mla_qkv(cfg, p, h, pos_b)
@@ -374,7 +464,7 @@ def decode_mla_block(cfg: ModelConfig, p: Dict, cache: Dict,
                    c_cache.astype(jnp.float32)) + \
         jnp.einsum("bqhr,bcr->bhqc", q_rope.astype(jnp.float32),
                    r_cache.astype(jnp.float32))
-    s = s * (nope + rope) ** -0.5
+    s = s * _mla_scale(cfg)
     cpos = _cache_positions(C, pos)
     s = jnp.where(cpos[None, None, None] <= pos, s, -1e30)
     pw = jax.nn.softmax(s, axis=-1)
@@ -383,8 +473,8 @@ def decode_mla_block(cfg: ModelConfig, p: Dict, cache: Dict,
     y = unproj_heads(o.astype(x_t.dtype), p["wo"])[:, 0]
     x_t = x_t + y
     h2 = rms_norm(x_t, p["mlp_norm"], cfg.rms_eps)
-    x_t = x_t + _mlp(cfg, p, h2)
-    return {"c_kv": c_cache, "k_rope": r_cache}, x_t
+    y2, _ = _mla_ffn(cfg, p, h2[:, None])
+    return {"c_kv": c_cache, "k_rope": r_cache}, x_t + y2[:, 0]
 
 
 # ==================================================================== ssm

@@ -3,7 +3,10 @@
 Families:
   dense   — standard decoder (GQA/MQA attention + gated MLP)
   moe     — dense attention + mixture-of-experts MLP
-  mla     — multi-head latent attention (MiniCPM3 / DeepSeek-style)
+  mla     — multi-head latent attention (MiniCPM3 / DeepSeek-style); with
+            experts (``n_experts`` > 0) the DeepSeek-V2 block: latent
+            attention, then routed experts beside shared ones, after
+            ``first_k_dense`` leading layers with a dense MLP
   ssm     — attention-free Mamba-2 (SSD) stack
   hybrid  — parallel attention + SSM heads per block (Hymba)
 """
@@ -28,17 +31,34 @@ class ModelConfig:
     head_dim: int = 0
     window: Optional[int] = None      # sliding-window size (SWA) or None
     rope_theta: float = 10_000.0
+    # YaRN (rope_scaling type "yarn", DeepSeek-V2); factor 1 = plain RoPE
+    yarn_factor: float = 1.0
+    yarn_original_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    rope_interleave: bool = False     # rotary pairs (2i, 2i+1), DeepSeek-V2
 
     # ---- mlp ----
     d_ff: int = 0
     act: str = "swiglu"               # swiglu | geglu
 
     # ---- moe ----
-    n_experts: int = 0
+    n_experts: int = 0                # experts held here
     top_k: int = 0
     d_ff_expert: int = 0
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    # DeepSeek-V2 (family "mla"): the router scores n_routed_experts (0:
+    # n_experts) and this chip holds [expert_offset, +n_experts) of them;
+    # n_shared_experts run on every token; gates are the top-k softmax
+    # scores times routed_scaling; the first first_k_dense layers are dense
+    n_routed_experts: int = 0
+    expert_offset: int = 0
+    n_shared_experts: int = 0
+    routed_scaling: float = 1.0
+    first_k_dense: int = 0
 
     # ---- mla (minicpm3 / deepseek style) ----
     q_lora_rank: int = 0
@@ -107,6 +127,15 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
+    def router_width(self) -> int:
+        """Experts the router scores (all of them, not only those held)."""
+        return self.n_routed_experts or self.n_experts
+
+    def dense_lead(self) -> "ModelConfig":
+        """The configuration of the leading dense layers."""
+        return self.replace(n_experts=0, first_k_dense=0)
+
+    @property
     def sub_quadratic(self) -> bool:
         """True if long-context decode is feasible (bounded cache)."""
         return self.family == "ssm" or (
@@ -127,6 +156,12 @@ class ModelConfig:
         if not self.tie_embeddings:
             total += d * V                    # lm head
         total += d                            # final norm
+        lead = self.first_k_dense
+        return total + lead * self.dense_lead()._layer_params() \
+            + (self.n_layers - lead) * self._layer_params()
+
+    def _layer_params(self) -> int:
+        d = self.d_model
         per_layer = 0
         if self.family in ("dense", "moe", "hybrid"):
             per_layer += 2 * d                # attn norm + mlp norm
@@ -141,7 +176,10 @@ class ModelConfig:
             qr, kr = self.q_lora_rank, self.kv_lora_rank
             nope, rope, vh = self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim
             H = self.n_heads
-            per_layer += d * qr + qr + qr * H * (nope + rope)      # q path
+            if qr:
+                per_layer += d * qr + qr + qr * H * (nope + rope)  # q path
+            else:
+                per_layer += d * H * (nope + rope)                 # wq
             per_layer += d * (kr + rope) + kr                      # kv compress
             per_layer += kr * H * (nope + vh)                      # kv expand
             per_layer += H * vh * d                                # out proj
@@ -156,16 +194,18 @@ class ModelConfig:
             if self.family == "ssm":
                 per_layer += d                                     # block norm
         if self.is_moe:
-            per_layer += d * self.n_experts                        # router
-            per_layer += self.n_experts * 3 * d * self.d_ff_expert
+            per_layer += d * self.router_width                     # router
+            per_layer += (self.n_experts + self.n_shared_experts) \
+                * 3 * d * self.d_ff_expert
         elif self.family in ("dense", "mla", "hybrid"):
             per_layer += 3 * d * self.d_ff
-        return total + self.n_layers * per_layer
+        return per_layer
 
     def active_param_count(self) -> int:
         """Params touched per token (MoE: top_k experts only)."""
         if not self.is_moe:
             return self.param_count()
-        inactive = self.n_layers * (self.n_experts - self.top_k) \
+        inactive = (self.n_layers - self.first_k_dense) \
+            * (self.n_experts - self.top_k) \
             * 3 * self.d_model * self.d_ff_expert
         return self.param_count() - inactive

@@ -1,6 +1,8 @@
 """Shared neural-net primitives (pure jnp, SPMD-friendly)."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -52,18 +54,58 @@ def rope_freqs(head_dim: int, theta: float) -> np.ndarray:
                             / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor (DeepSeek-V2's yarn_get_mscale)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_freqs(dim: int, base: float, factor: float, original_len: int,
+               beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies, as DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``: the extrapolated frequencies above
+    the correction range, the interpolated ones (divided by ``factor``)
+    below it, and a linear ramp between."""
+    extra = rope_freqs(dim, base)
+    inter = extra / factor
+
+    def corr(rot):
+        return dim * math.log(original_len / (rot * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    mask = 1.0 - ramp
+    return (inter * (1 - mask) + extra * mask).astype(np.float32)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs=None, interleave: bool = False,
+               scale: float = 1.0) -> jax.Array:
     """x: (..., S, H, D) or (..., S, D); positions: broadcastable to (..., S).
 
-    Rotates the full last dim (D must be even), interleaved-pair convention.
+    Rotates the full last dim (D must be even) in rotate-half pairs
+    (i, i + D/2). ``freqs`` replaces the plain inverse frequencies (YaRN);
+    ``interleave`` first de-interleaves the pairs (2i, 2i+1), as
+    DeepSeek-V2 does; ``scale`` multiplies cos and sin.
     """
     D = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(D, theta))          # (D/2,)
+    if freqs is None:
+        freqs = rope_freqs(D, theta)
+    freqs = jnp.asarray(freqs)                          # (D/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs   # (..., S, D/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     if x.ndim == ang.ndim + 1:                          # has head axis
         cos, sin = cos[..., None, :], sin[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    xf = x.astype(jnp.float32)
+    if interleave:
+        xf = jnp.concatenate([xf[..., 0::2], xf[..., 1::2]], axis=-1)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 

@@ -3,7 +3,10 @@
 Parameters are layer-stacked (every block leaf gets a leading ``n_layers``
 dim) and applied with ``lax.scan`` so the HLO stays O(1) in depth — critical
 for 62-layer models compiled for 512 SPMD devices. Rematerialization policy
-is applied to the scanned block body.
+is applied to the scanned block body. A model whose first layers differ
+(DeepSeek-V2's leading dense layers) keeps them as a stack of their own,
+``dense_blocks``, scanned before ``blocks``; ``LAYER_STACKS`` names the
+per-layer subtrees.
 
 The LM head / CE loss is computed *chunked over the sequence* so the full
 (B, S, V) logits tensor is never materialized (vocab up to 256k).
@@ -16,11 +19,23 @@ import jax
 import jax.numpy as jnp
 
 from ..sharding.ctx import constrain
-from .blocks import apply_block, decode_block, init_block, init_layer_cache
+from .blocks import (MOE_COUNTERS, apply_block, aux_zero, decode_block,
+                     init_block, init_layer_cache)
 from .config import ModelConfig
 from .layers import dense, rms_norm, trunc_normal
 
 LOSS_CHUNK = 512
+LAYER_STACKS = ("dense_blocks", "blocks")
+# metrics of the step that add up over its microbatches (not averaged)
+STEP_COUNTERS = tuple(f"moe_{k}" for k in MOE_COUNTERS)
+
+
+def layer_stacks(cfg: ModelConfig):
+    """(subtree name, block configuration, layers) of each stack, in the
+    order they are applied."""
+    lead = cfg.first_k_dense
+    out = [("dense_blocks", cfg.dense_lead(), lead)] if lead else []
+    return out + [("blocks", cfg, cfg.n_layers - lead)]
 
 
 # ------------------------------------------------------------------- params
@@ -30,10 +45,12 @@ def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
     Vp = padded_vocab(cfg)
     params = {
         "embed": trunc_normal(k_embed, (Vp, cfg.d_model), 1.0, dt),
-        "blocks": jax.vmap(lambda k: init_block(cfg, k))(
-            jax.random.split(k_blocks, cfg.n_layers)),
         "final_norm": jnp.ones((cfg.d_model,), jnp.float32),
     }
+    for name, lcfg, n in layer_stacks(cfg):
+        k = k_blocks if name == "blocks" else jax.random.fold_in(k_blocks, 1)
+        params[name] = jax.vmap(lambda k: init_block(lcfg, k))(
+            jax.random.split(k, n))
     if not cfg.tie_embeddings:
         params["lm_head"] = trunc_normal(k_head, (cfg.d_model, Vp),
                                          cfg.d_model ** -0.5, dt)
@@ -82,17 +99,28 @@ def embed_tokens(cfg: ModelConfig, params: Dict, tokens: jax.Array,
     return x
 
 
+def _scan_layers(cfg: ModelConfig, params: Dict, x: jax.Array,
+                 positions: jax.Array, collect_cache: bool = False):
+    """Every stack of blocks in turn. Returns (hidden, the last stack's
+    summed aux, the caches as a dict by stack name)."""
+    caches = {}
+    for name, lcfg, _ in layer_stacks(cfg):
+        def layer(carry, p, lcfg=lcfg):
+            h, aux = carry
+            h, a, cache = apply_block(lcfg, p, h, positions, collect_cache)
+            return (h, jax.tree.map(jnp.add, aux, a)), cache
+
+        (x, aux), caches[name] = jax.lax.scan(
+            _remat(lcfg, layer), (x, aux_zero(lcfg)), params[name])
+    return x, aux, caches
+
+
 def backbone(cfg: ModelConfig, params: Dict, x: jax.Array,
-             positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Apply all blocks. Returns (hidden, aux_loss_sum)."""
-
-    def layer(carry, p):
-        h, aux = carry
-        h, a, _ = apply_block(cfg, p, h, positions)
-        return (h, aux + a), None
-
-    (x, aux), _ = jax.lax.scan(_remat(cfg, layer),
-                               (x, jnp.float32(0.0)), params["blocks"])
+             positions: jax.Array) -> Tuple[jax.Array, Any]:
+    """Apply all blocks. Returns (hidden, aux summed over the layers: the
+    router's loss, or a dict of it and the MoE counters). The leading dense
+    layers add no router term."""
+    x, aux, _ = _scan_layers(cfg, params, x, positions)
     return rms_norm(x, params["final_norm"], cfg.rms_eps), aux
 
 
@@ -144,6 +172,11 @@ def loss_fn(cfg: ModelConfig, params: Dict, batch: Dict[str, jax.Array]
     x = embed_tokens(cfg, params, tokens, batch.get("prefix_embeds"))
     hidden, aux = backbone(cfg, params, x, positions)
     ce = token_loss(cfg, params, hidden, batch["labels"], batch["mask"])
+    if isinstance(aux, dict):
+        # DeepSeek-V2: the balance loss of every MoE layer, summed
+        loss = ce + cfg.router_aux_coef * aux["balance"]
+        return loss, {"ce": ce, "aux": aux["balance"],
+                      **{f"moe_{k}": aux[k] for k in MOE_COUNTERS}}
     loss = ce + cfg.router_aux_coef * aux / max(cfg.n_layers, 1)
     return loss, {"ce": ce, "aux": aux}
 
@@ -154,19 +187,15 @@ def prefill(cfg: ModelConfig, params: Dict, tokens: jax.Array,
             ) -> Tuple[Dict, jax.Array]:
     """Full-sequence forward that also builds the decode cache.
 
-    Returns (cache pytree stacked over layers, last-position logits (B, V)).
+    Returns (cache: a dict by stack name of pytrees stacked over layers,
+    last-position logits (B, V)).
     """
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     x = embed_tokens(cfg, params, tokens, prefix_embeds)
 
-    def layer(carry, p):
-        h, aux = carry
-        h, a, cache = apply_block(cfg, p, h, positions, collect_cache=True)
-        return (h, aux + a), cache
-
-    (x, _), caches = jax.lax.scan(_remat(cfg, layer),
-                                  (x, jnp.float32(0.0)), params["blocks"])
+    x, _, caches = _scan_layers(cfg, params, x, positions,
+                                collect_cache=True)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = dense(x[:, -1], lm_head_weight(cfg, params)) \
         .astype(jnp.dtype(cfg.logit_dtype))
@@ -175,10 +204,12 @@ def prefill(cfg: ModelConfig, params: Dict, tokens: jax.Array,
 
 
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int):
-    """Zeroed decode cache stacked over layers."""
+    """Zeroed decode cache: a dict by stack name of caches stacked over
+    that stack's layers."""
     one = init_layer_cache(cfg, batch, cfg.cache_len(cache_len))
-    return jax.tree.map(
-        lambda a: jnp.broadcast_to(a, (cfg.n_layers,) + a.shape).copy(), one)
+    return {name: jax.tree.map(
+        lambda a, n=n: jnp.broadcast_to(a, (n,) + a.shape).copy(), one)
+        for name, _, n in layer_stacks(cfg)}
 
 
 def decode_step(cfg: ModelConfig, params: Dict, cache, tokens: jax.Array,
@@ -189,13 +220,15 @@ def decode_step(cfg: ModelConfig, params: Dict, cache, tokens: jax.Array,
         .astype(jnp.dtype(cfg.compute_dtype))
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    new_cache = {}
+    for name, lcfg, _ in layer_stacks(cfg):
+        def layer(x_t, inp, lcfg=lcfg):
+            p, c = inp
+            c, x_t = decode_block(lcfg, p, c, x_t, pos)
+            return x_t, c
 
-    def layer(x_t, inp):
-        p, c = inp
-        c, x_t = decode_block(cfg, p, c, x_t, pos)
-        return x_t, c
-
-    x, new_cache = jax.lax.scan(layer, x, (params["blocks"], cache))
+        x, new_cache[name] = jax.lax.scan(layer, x,
+                                          (params[name], cache[name]))
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = dense(x, lm_head_weight(cfg, params)) \
         .astype(jnp.dtype(cfg.logit_dtype))

@@ -123,3 +123,97 @@ def moe_ffn_reference(x, w_router, w_gate, w_up, w_down, *, top_k,
         w_e = jnp.sum(jnp.where(experts == e, gate, 0.0), axis=-1)
         out = out + y.astype(jnp.float32) * w_e[:, None]
     return out.astype(x.dtype), aux
+
+
+# ======================================================= held experts
+# DeepSeek-V2's routed experts under expert parallelism: the router scores
+# every routed expert, each token takes the greedy top-k of the softmax
+# scores as its gates (not renormalised, times a scaling factor), and this
+# chip computes the part of the result that the experts it holds give,
+# for every assignment they receive: no capacity, nothing dropped.
+
+
+def route_topk(x: jax.Array, w_router: jax.Array, top_k: int,
+               scaling: float = 1.0
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x: (T, d) -> (gates (T,k) f32, experts (T,k) int32, scores (T,E))."""
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                        w_router.astype(jnp.float32))
+    scores = jax.nn.softmax(logits, axis=-1)
+    gate, experts = jax.lax.top_k(scores, top_k)
+    return gate * scaling, experts, scores
+
+
+def seq_balance_loss(scores: jax.Array, experts: jax.Array) -> jax.Array:
+    """DeepSeek-V2's sequence-wise balance loss. scores: (B, S, E) softmax
+    scores; experts: (B, S, k) chosen experts. Per sequence,
+    f_e = E / (k S) * #{t : e chosen at t} and P_e = mean_t s_{t,e}; the
+    loss is the mean over sequences of sum_e f_e P_e."""
+    B, S, E = scores.shape
+    k = experts.shape[-1]
+    counts = jax.nn.one_hot(experts.reshape(B, S * k), E,
+                            dtype=jnp.float32).sum(1)            # (B, E)
+    f = counts * (E / (k * S))
+    return jnp.mean(jnp.sum(f * scores.mean(1), axis=-1))
+
+
+@jax.custom_vjp
+def _permute(x: jax.Array, order: jax.Array, inverse: jax.Array
+             ) -> jax.Array:
+    """Rows of x in ``order``; the gradient is a gather by ``inverse``
+    (no scatter)."""
+    return x[order]
+
+
+def _permute_fwd(x, order, inverse):
+    return x[order], inverse
+
+
+def _permute_bwd(inverse, g):
+    return g[inverse], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def held_experts(x: jax.Array, gate: jax.Array, experts: jax.Array,
+                 w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                 offset: int = 0) -> Tuple[jax.Array, jax.Array]:
+    """sum over the chosen experts held here of gate * SwiGLU_e(x).
+
+    x: (T, d); gate, experts: (T, k) over all routed experts; w_*: the
+    held experts' weights (E, d, f) / (E, f, d), experts
+    [offset, offset + E). The assignments are sorted by held expert (the
+    others last) and run as grouped matmuls (``lax.ragged_dot``) over the
+    groups' sizes, so the work follows the assignments received. Returns
+    (out (T, d), assignments per held expert (E,) int32)."""
+    T, d = x.shape
+    k = experts.shape[-1]
+    E = w_gate.shape[0]
+    local = experts.reshape(-1) - offset
+    held = (local >= 0) & (local < E)
+    key = jnp.where(held, local, E)
+    order = jnp.argsort(key, stable=True)
+    inverse = jnp.argsort(order)
+    sizes = jnp.bincount(key, length=E + 1)[:E].astype(jnp.int32)
+    # The grouped matmul leaves the rows past the groups undefined on the
+    # TPU, in its result and in the gradient of its left operand. Select,
+    # never multiply, them away on both sides of each matmul, so that
+    # neither the result nor the gradient of x reads them.
+    in_groups = (jnp.arange(T * k) < sizes.sum())[:, None]
+    xs = _permute(jnp.repeat(x, k, axis=0), order, inverse)      # (T*k, d)
+    xs = jnp.where(in_groups, xs, 0)
+    dt = x.dtype
+
+    def grouped(lhs, w):
+        return jnp.where(in_groups,
+                         jax.lax.ragged_dot(lhs, w.astype(dt), sizes), 0)
+
+    y = grouped(jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up),
+                w_down)
+    y = _permute(y, inverse, order)                 # assignment order
+    y = jnp.where(held[:, None], y, 0).reshape(T, k, d)
+    w = jnp.where(held, gate.reshape(-1), 0.0).reshape(T, k)
+    out = jnp.einsum("tkd,tk->td", y, w.astype(dt),
+                     preferred_element_type=jnp.float32)
+    return out.astype(dt), sizes

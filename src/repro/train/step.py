@@ -24,6 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import (decode_step as model_decode, init_cache, init_params,
                       loss_fn, prefill as model_prefill)
+from ..models.model import STEP_COUNTERS
 from ..models.config import ModelConfig
 from ..optim import AdamWConfig, apply_update, init_opt_state
 from ..sharding.ctx import activation_ctx
@@ -114,9 +115,11 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
                                                             mbs)
                     grads = jax.tree.map(lambda g: g / nmicro, grads)
                     # the step's loss is the mean over its microbatches,
-                    # as its gradient is
+                    # as its gradient is; counters add up
                     loss = losses.mean()
-                    metrics = jax.tree.map(lambda a: a.mean(0), metrics)
+                    metrics = {k: a.sum(0) if k in STEP_COUNTERS
+                               else a.mean(0)
+                               for k, a in sorted(metrics.items())}
             if tcfg.grad_reduce_dtype is not None:
                 rd = jnp.dtype(tcfg.grad_reduce_dtype)
                 grads = jax.tree.map(lambda g: g.astype(rd), grads)
